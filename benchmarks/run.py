@@ -268,6 +268,9 @@ def run_all(full: bool) -> list[str]:
 
 
 def main() -> None:
+    from repro.runtime.compile_cache import use_compile_cache
+
+    use_compile_cache()
     if "--smoke" in sys.argv:
         out = run_smoke()
     else:

@@ -65,14 +65,17 @@ ARTIFACTS = CompiledArtifactCache()
 
 class ComputeRuntime:
     """Executes ``compute`` tasks: builds/fetches the compiled step and runs a
-    reduced-config instance on the provider's devices (CPU container)."""
+    reduced-config instance on the device the provider hands it.  Programs
+    and model state are kept per device."""
 
     def __init__(self):
         self._states: dict[tuple, Any] = {}
         self._lock = threading.Lock()
 
-    def run(self, task: Task) -> Any:
+    def run(self, task: Task, device) -> Any:
         import jax
+        import numpy as np
+        from jax.sharding import Mesh
 
         from repro.configs import get_arch
         from repro.data.pipeline import DataConfig, batch_at
@@ -83,13 +86,11 @@ class ComputeRuntime:
 
         arch = get_arch(task.arch).reduced()
         step_kind = task.step_kind or "train"
-        key = (task.arch, step_kind)
+        key = (task.arch, step_kind, device)
 
         def build():
-            from repro.compat import compat_make_mesh
-
             model = Model(arch)
-            mesh = compat_make_mesh((1,), ("data",))
+            mesh = Mesh(np.array([device]), ("data",))
             strategy = STRATEGIES["tp"]
             if step_kind == "train":
                 fn = jax.jit(
@@ -107,21 +108,22 @@ class ComputeRuntime:
             enc_len=arch.enc_len_train, d_model=arch.d_model,
             n_img_tokens=arch.n_img_tokens, family=arch.family,
         )
-        batch = batch_at(dc, task.retries)
+        batch = jax.device_put(batch_at(dc, task.retries), device)
         with self._lock:
             state = self._states.get(key)
             if state is None:
-                import jax as _jax
-
-                state = step_lib.init_train_state(model, _jax.random.key(0))
+                state = jax.device_put(
+                    step_lib.init_train_state(model, jax.random.key(0)), device
+                )
                 self._states[key] = state
+        placed = {"device": device.id, "platform": device.platform}
         if step_kind == "train":
             params, opt, metrics = fn(state[0], state[1], batch)
             with self._lock:
                 self._states[key] = (params, opt)
-            return {k: float(v) for k, v in metrics.items()}
+            return {**{k: float(v) for k, v in metrics.items()}, **placed}
         logits, _ = fn(state[0], {k: v for k, v in batch.items() if k != "labels"})
-        return {"logits_shape": list(logits.shape)}
+        return {"logits_shape": list(logits.shape), **placed}
 
 
 COMPUTE_RUNTIME = ComputeRuntime()
@@ -139,13 +141,16 @@ class KernelRuntime:
 
     Block-config resolution mirrors kernels/ops.py: explicit payload config
     > autotuned cache (``HYDRA_AUTOTUNE=1`` only) > the kernel's committed
-    defaults.  Execution is rep-granular and resumable: ``progress_frac``
-    advances after every completed repetition, so a preempt-killed task that
-    the checkpointer resumes (ckpt/checkpoint.py) skips the reps it already
-    finished — only the partial rep in flight is re-executed.
+    defaults.  Operands are built on ``device`` from the seed and the kernel
+    runs there as one compiled program (kernels/registry.py ``compiled``).
+    Execution is rep-granular and resumable: ``progress_frac`` advances
+    after every completed repetition, so a preempt-killed task that the
+    checkpointer resumes (ckpt/checkpoint.py) skips the reps it already
+    finished — only the partial rep in flight is re-executed.  The result
+    names the device and carries the output's checksum.
     """
 
-    def run(self, task: Task) -> Any:
+    def run(self, task: Task, device) -> Any:
         import time as _time
 
         import jax
@@ -160,13 +165,13 @@ class KernelRuntime:
         reps = max(1, int(spec.get("reps", 1)))
         seed = int(spec.get("seed", 0))
         config = spec.get("config") or tuned_config(kdef.name, shape, dtype) or kdef.defaults(shape)
-        interpret = kreg.interpret_default()
-        args = kdef.make_args(shape, dtype, seed)
+        program = kreg.compiled(kdef, shape, dtype, config, device)
+        args = kreg.operands(kdef, shape, dtype, seed, device)
         done = min(reps, int(round(task.progress_frac * reps)))
         out = None
         t0 = _time.perf_counter()
         for r in range(done, reps):
-            out = kdef.call(shape, args, config, interpret)
+            out = program(*args)
             jax.block_until_ready(out)
             # completed-rep boundary: durable progress the checkpointer can
             # capture without losing more than the rep in flight
@@ -190,6 +195,9 @@ class KernelRuntime:
             "reps": reps,
             "skipped_reps": done,
             "kernel_s": kernel_s,
+            "device": device.id,
+            "platform": device.platform,
+            "checksum": None if out is None else kreg.checksum(out),
         }
 
 
@@ -297,7 +305,7 @@ class CaaSManager:
         task.trace.add("exec_start")
         try:
             result = self._execute(task)
-        except BaseException as e:
+        except Exception as e:
             if task.mark_failed(e):
                 with self._lock:
                     self.failed += 1
@@ -335,7 +343,7 @@ class CaaSManager:
         if task.kind == "callable":
             return task.fn() if task.fn else None
         if task.kind == "compute":
-            return COMPUTE_RUNTIME.run(task)
+            return COMPUTE_RUNTIME.run(task, self.handle.next_device())
         if task.kind == "kernel":
-            return KERNEL_RUNTIME.run(task)
+            return KERNEL_RUNTIME.run(task, self.handle.next_device())
         raise ValueError(task.kind)

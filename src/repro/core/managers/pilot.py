@@ -137,12 +137,12 @@ class PilotManager:
             elif task.kind == "callable":
                 result = task.fn() if task.fn else None
             elif task.kind == "compute":
-                result = COMPUTE_RUNTIME.run(task)
+                result = COMPUTE_RUNTIME.run(task, self.handle.next_device())
             elif task.kind == "kernel":
-                result = KERNEL_RUNTIME.run(task)
+                result = KERNEL_RUNTIME.run(task, self.handle.next_device())
             else:
                 raise ValueError(task.kind)
-        except BaseException as e:
+        except Exception as e:
             if task.mark_failed(e):
                 with self._stats_lock:
                     self.failed += 1

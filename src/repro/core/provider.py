@@ -79,10 +79,19 @@ class ProviderHandle:
     outstanding: int = 0
     load_lock: threading.Lock = field(default_factory=threading.Lock)
     trace: Trace = field(default_factory=Trace)
+    _next_device: int = field(default=0, init=False, repr=False)
 
     @property
     def name(self) -> str:
         return self.spec.name
+
+    def next_device(self):
+        """The device of this provider's slice that runs the next
+        single-device task: round-robin over the slice."""
+        with self.load_lock:
+            i = self._next_device
+            self._next_device = i + 1
+        return self.devices[i % len(self.devices)]
 
 
 class ProviderProxy:
@@ -264,18 +273,18 @@ class ProviderProxy:
         if spec.connector not in ("caas", "pilot"):
             raise ValidationError(f"provider {spec.name!r}: unknown connector {spec.connector!r}")
 
-    def _validate_devices(self, spec: ProviderSpec) -> list:
+    @staticmethod
+    def _validate_devices(spec: ProviderSpec) -> list:
+        """The provider's slice ``[device_offset, device_offset + n_devices)``
+        of the visible devices.  A slice that does not exist is refused;
+        providers may share devices (every one at offset 0 shares device 0)."""
         devs = jax.devices()
         lo, hi = spec.device_offset, spec.device_offset + spec.n_devices
         if spec.n_devices < 1:
             raise ValidationError(f"provider {spec.name!r}: n_devices must be >= 1")
-        if hi > len(devs):
-            # device pools may logically share the single CPU device in this
-            # container; only reject if the pool is empty
-            if spec.device_offset >= len(devs):
-                slice_ = [devs[spec.device_offset % len(devs)]]
-            else:
-                slice_ = devs[lo:]
-        else:
-            slice_ = devs[lo:hi]
-        return list(slice_)
+        if lo < 0 or hi > len(devs):
+            raise ValidationError(
+                f"provider {spec.name!r}: devices [{lo}, {hi}) do not exist; "
+                f"{len(devs)} visible"
+            )
+        return devs[lo:hi]

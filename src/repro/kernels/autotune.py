@@ -34,11 +34,12 @@ timings — so identically-seeded runs produce byte-identical payloads and
 the determinism test can compare them directly.  A cache hit returns the
 stored result without re-timing and without emitting ``kernel.tune``.
 
-Timers: ``timer="wall"`` (default) measures real executions of the
-interpret path on this container (Mosaic on a real TPU); ``timer="model"``
-scores candidates purely with the roofline expression above — fully
-deterministic, used by the determinism tests and the dry-run report's
-predicted-config rows.
+Timers: ``timer="wall"`` (default) times the compiled program a kernel task
+would run on the first device (interpreted off a TPU, Mosaic on one);
+``timer="model"`` scores candidates purely with the roofline expression
+above, priced at the published peaks of a TPU v5e — fully deterministic,
+used by the determinism tests and the dry-run report's predicted-config
+rows.
 
 ``ops.py`` consults the process-global tuner (:func:`tuned_config`) only
 when ``HYDRA_AUTOTUNE=1``; with the gate off every entry point falls back
@@ -55,7 +56,7 @@ from dataclasses import dataclass, field
 from typing import Callable, Optional
 
 from repro.kernels import registry as kreg
-from repro.roofline.model import HBM_BW, PEAK_FLOPS
+from repro.roofline.model import V5E, peaks
 
 # v5e per-core VMEM budget (see kernels/flash_attention.py footprint note)
 VMEM_BUDGET_BYTES = 16 * 1024 * 1024
@@ -67,6 +68,9 @@ VMEM_BUDGET_BYTES = 16 * 1024 * 1024
 # measures: its per-cell Python dispatch dominates at bench shapes).
 MODEL_CELL_OVERHEAD_S = 1e-6
 
+# the chip the modeled timer prices: the v5e the dry-run report models
+MODEL_CHIP = peaks(V5E)
+
 PAYLOAD_VERSION = 1
 
 
@@ -76,9 +80,10 @@ def autotune_enabled() -> bool:
 
 
 def device_kind() -> str:
+    """The running chip's ``device_kind``, made a stable key fragment."""
     import jax
 
-    return jax.default_backend()
+    return jax.devices()[0].device_kind.replace(" ", "-")
 
 
 @dataclass
@@ -194,9 +199,10 @@ class Autotuner:
 
     @staticmethod
     def model_time_s(cost: kreg.Cost) -> float:
-        """Roofline-modeled seconds: max(compute, memory) + launch tax."""
+        """Roofline-modeled seconds on a v5e: max(compute, memory) +
+        launch tax."""
         return (
-            max(cost.flops / PEAK_FLOPS, cost.hbm_bytes / HBM_BW)
+            max(cost.flops / MODEL_CHIP.flops, cost.hbm_bytes / MODEL_CHIP.hbm_bw)
             + cost.grid_cells * MODEL_CELL_OVERHEAD_S
         )
 
@@ -214,14 +220,16 @@ class Autotuner:
                 return TuneResult(**{**vars(hit), "cached": True})
             kdef = kreg.get_kernel(kernel)
             survivors, exhaustive = self.prune(kernel, shape, dtype)
-            interpret = kreg.interpret_default()
-            args = None
             if self.timer == "wall":
-                args = kdef.make_args(shape, dtype, self.seed)
+                import jax
+
+                device = jax.devices()[0]
+                args = kreg.operands(kdef, shape, dtype, self.seed, device)
             best_cfg, best_s, timings = None, float("inf"), {}
             for cfg in survivors:
                 if self.timer == "wall":
-                    t = self._time_wall(lambda: kdef.call(shape, args, cfg, interpret))
+                    program = kreg.compiled(kdef, shape, dtype, cfg, device)
+                    t = self._time_wall(lambda: program(*args))
                 else:
                     t = self.model_time_s(kdef.cost(shape, cfg, dtype))
                 timings[kreg.config_sig(cfg)] = t

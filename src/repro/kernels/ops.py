@@ -1,6 +1,6 @@
 """Jit'd dispatch wrappers over the Pallas kernels.
 
-On non-TPU backends (this CPU container) the kernels execute in interpret
+Off a TPU (registry.interpret_default) the kernels execute in interpret
 mode - the kernel body runs step-by-step in Python/XLA so correctness (and
 the BlockSpec tiling logic) is fully exercised without Mosaic.  On a real
 v5e these same calls lower to Mosaic TPU kernels.
@@ -28,10 +28,7 @@ from repro.kernels import moe_gmm as _gmm
 from repro.kernels import rglru_scan as _rg
 from repro.kernels import selective_scan as _ss
 from repro.kernels.autotune import tuned_config
-
-
-def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+from repro.kernels.registry import interpret_default
 
 
 def _resolve(kernel: str, shape: dict, dtype, defaults: dict, explicit: dict) -> dict:
@@ -49,7 +46,7 @@ def _resolve(kernel: str, shape: dict, dtype, defaults: dict, explicit: dict) ->
 def _flash_attention_jit(q, k, v, *, causal, window, block_q, block_k):
     return _fa.flash_attention(
         q, k, v, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, interpret=_interpret(),
+        block_q=block_q, block_k=block_k, interpret=interpret_default(),
     )
 
 
@@ -76,7 +73,7 @@ def flash_attention(
 
 @partial(jax.jit, static_argnames=("block_d",))
 def _selective_scan_jit(x, dt, b, c, a, h0, *, block_d):
-    return _ss.selective_scan_chunk(x, dt, b, c, a, h0, block_d=block_d, interpret=_interpret())
+    return _ss.selective_scan_chunk(x, dt, b, c, a, h0, block_d=block_d, interpret=interpret_default())
 
 
 def selective_scan_chunk(x, dt, b, c, a, h0, *, block_d: Optional[int] = None):
@@ -92,7 +89,7 @@ def selective_scan_chunk(x, dt, b, c, a, h0, *, block_d: Optional[int] = None):
 
 @partial(jax.jit, static_argnames=("block_d",))
 def _rglru_scan_jit(log_a, gx, h0, *, block_d):
-    return _rg.rglru_scan(log_a, gx, h0, block_d=block_d, interpret=_interpret())
+    return _rg.rglru_scan(log_a, gx, h0, block_d=block_d, interpret=interpret_default())
 
 
 def rglru_scan(log_a, gx, h0=None, *, block_d: Optional[int] = None):
@@ -108,7 +105,7 @@ def rglru_scan(log_a, gx, h0=None, *, block_d: Optional[int] = None):
 
 @partial(jax.jit, static_argnames=("block_c", "block_f", "block_d"))
 def _moe_gmm_jit(x, w, *, block_c, block_f, block_d):
-    return _gmm.moe_gmm(x, w, block_c=block_c, block_f=block_f, block_d=block_d, interpret=_interpret())
+    return _gmm.moe_gmm(x, w, block_c=block_c, block_f=block_f, block_d=block_d, interpret=interpret_default())
 
 
 def moe_gmm(

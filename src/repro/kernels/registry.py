@@ -24,11 +24,13 @@ tests.
 """
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass
 from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import flash_attention as _fa
 from repro.kernels import moe_gmm as _gmm
@@ -73,6 +75,16 @@ class KernelDef:
 
 def _isz(dtype: str) -> int:
     return jnp.dtype(dtype).itemsize
+
+
+def _lanes(n: int) -> int:
+    """Minor dims are laid out in 128-lane vregs: a tile pays for whole ones."""
+    return -(-n // 128) * 128
+
+
+def _rows(n: int) -> int:
+    """Second-minor dims are laid out in 8-row sublane groups."""
+    return -(-n // 8) * 8
 
 
 def _divisors(dim: int, candidates=_BLOCK_CANDIDATES) -> list:
@@ -161,8 +173,9 @@ def _fa_cost(shape: dict, config: dict, dtype: str) -> Cost:
     # tile traffic per LAUNCHED cell (block copies happen even when masked):
     # q tile + k tile + v tile in, plus the output written once per q row
     hbm = isz * B * H * (nq * nk * (bq + 2 * bk) * hd + shape["L"] * hd)
-    # q/k/v input tiles + fp32 scratch (m, l, acc) + output tile
-    vmem = isz * (bq + 2 * bk) * hd + 4 * bq * (2 + hd) + isz * bq * hd
+    # q/k/v input tiles and the output tile, each double-buffered by the
+    # pipeline, + fp32 scratch (m and l are (bq, 1): a full lane row each)
+    vmem = 2 * isz * (bq + 2 * bk + bq) * hd + 4 * bq * (2 * 128 + hd)
     return Cost(flops, float(hbm), float(vmem), cells)
 
 
@@ -216,7 +229,12 @@ def _ss_cost(shape: dict, config: dict, dtype: str) -> Cost:
         + 4 * (2 * ck * N + 2 * bd * N)  # b, c, a, h0
         + 4 * (ck * bd + bd * N)  # y, h_last
     )
-    vmem = isz * ck * bd + 4 * (2 * ck * bd + 2 * ck * N + 3 * bd * N)
+    # every block double-buffered: x (dtype), dt, b, c, a^T, h0^T in; y, h^T
+    # out.  b/c blocks are (chunk, N) with N padded to whole lanes
+    vmem = 2 * (
+        isz * ck * bd + 4 * ck * bd + 2 * 4 * ck * _lanes(N)
+        + 2 * 4 * _rows(N) * bd + 4 * ck * bd + 4 * _rows(N) * bd
+    )
     return Cost(flops, float(cells * per_cell), float(vmem), cells)
 
 
@@ -253,14 +271,17 @@ def _rg_space(shape: dict) -> list:
 def _rg_cost(shape: dict, config: dict, dtype: str) -> Cost:
     B, L, dr = shape["B"], shape["L"], shape["dr"]
     bd = min(config["block_d"], dr)
-    cells = B * (dr // bd)
+    bl = _rg.seq_block(L)
+    cells = B * (dr // bd) * (L // bl)
     # exp + multiply-add per (t, channel); traffic is config-independent
     # (log_a/gx/y each touched once, h tiles sum to B*dr regardless of bd),
     # so the frontier collapses to minimum grid cells: the pruner keeps only
     # the largest admissible block
     flops = 3.0 * B * L * dr
     hbm = 4.0 * (3 * B * L * dr + 2 * B * dr)
-    vmem = 4.0 * (3 * L * bd + 2 * bd)
+    # log_a/gx in and y out as (bl, bd) tiles, h0/h_last as one-row blocks
+    # (a full sublane group each), all double-buffered; + the state scratch
+    vmem = 4.0 * (2 * (3 * bl * bd + 2 * 8 * bd) + 8 * bd)
     return Cost(flops, hbm, vmem, cells)
 
 
@@ -320,7 +341,8 @@ def _gmm_cost(shape: dict, config: dict, dtype: str) -> Cost:
     # x tiles re-fetched per f-block, w tiles per c-block, y written per
     # d-block (interpret copies the out tile back every cell)
     hbm = isz * (nf * E * C * D + nc * E * D * F + nd * E * C * F)
-    vmem = isz * (bc * bd + bd * bf + bc * bf) + 4 * bc * bf
+    # double-buffered x/w/y tiles + the fp32 accumulator
+    vmem = 2 * isz * (bc * bd + bd * bf + bc * bf) + 4 * bc * bf
     return Cost(flops, float(hbm), float(vmem), cells)
 
 
@@ -401,10 +423,97 @@ def config_sig(config: dict) -> str:
     return ",".join(f"{k}={config[k]}" for k in sorted(config))
 
 
-def interpret_default() -> bool:
-    """Interpret mode everywhere but a real TPU backend (same rule as
-    kernels/ops.py)."""
-    return jax.default_backend() != "tpu"
+def interpret_default(device=None) -> bool:
+    """The one rule for interpret mode: interpret unless the kernel runs on
+    a TPU (``device``, else the default backend)."""
+    platform = device.platform if device is not None else jax.default_backend()
+    return platform != "tpu"
+
+
+def published_shapes() -> dict:
+    """Each kernel at the widths of a published model that carries it:
+    name -> (arch, shape, dtypes).  One chip's share of the model: batch 1,
+    one expert, one SSM chunk."""
+    from repro.configs import get_arch
+
+    llama = get_arch("llama3-8b")
+    mamba = get_arch("falcon-mamba-7b")
+    rgemma = get_arch("recurrentgemma-2b")
+    grok = get_arch("grok-1-314b")
+    return {
+        "flash_attention": (llama.name, {
+            "B": 1, "H": llama.n_heads, "KV": llama.n_kv_heads, "L": 2048,
+            "hd": llama.head_dim, "causal": True, "window": None,
+        }, ("bfloat16",)),
+        "selective_scan": (mamba.name, {
+            "B": 1, "chunk": mamba.ssm_chunk, "di": mamba.d_inner, "N": mamba.ssm_state,
+        }, ("float32", "bfloat16")),
+        "rglru_scan": (rgemma.name, {
+            "B": 1, "L": rgemma.local_window, "dr": rgemma.rnn_width,
+        }, ("float32",)),
+        "moe_gmm": (grok.name, {
+            "E": 1, "C": 1024, "D": grok.d_model, "F": grok.d_ff,
+        }, ("bfloat16",)),
+    }
+
+
+# AOT executables and operand builders, one per (kernel, shape, dtype,
+# config, device): a kernel task compiles once per device and every later
+# task on that device reuses the program
+_PROGRAMS: dict = {}
+_PROGRAMS_LOCK = threading.Lock()
+
+
+def _program(key: tuple, build: Callable[[], Any]):
+    with _PROGRAMS_LOCK:
+        prog = _PROGRAMS.get(key)
+        if prog is None:
+            prog = _PROGRAMS[key] = build()
+        return prog
+
+
+def operands(kdef: KernelDef, shape: dict, dtype: str, seed: int, device):
+    """``make_args`` compiled for ``device``: the operands are built there,
+    so the kernel call that takes them runs there too."""
+    key = ("args", kdef.name, shape_sig(shape, dtype), device)
+    make = _program(key, lambda: jax.jit(
+        lambda s: kdef.make_args(shape, dtype, s),
+        out_shardings=SingleDeviceSharding(device),
+    ))
+    return make(jnp.int32(seed))
+
+
+def compiled(kdef: KernelDef, shape: dict, dtype: str, config: dict, device):
+    """The kernel at (shape, dtype, config) compiled for ``device``.  On a
+    TPU the program must hold the Mosaic kernel (``tpu_custom_call``): a
+    silently interpreted or XLA-lowered kernel is an error, not a fallback."""
+    interpret = interpret_default(device)
+    key = ("call", kdef.name, shape_sig(shape, dtype), config_sig(config), device)
+
+    def build():
+        sharding = SingleDeviceSharding(device)
+        avals = jax.eval_shape(lambda: kdef.make_args(shape, dtype, 0))
+        specs = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding) for a in avals]
+        prog = jax.jit(lambda *a: kdef.call(shape, a, config, interpret)).lower(*specs).compile()
+        if not interpret and "tpu_custom_call" not in prog.as_text():
+            raise RuntimeError(
+                f"{kdef.name} at {shape_sig(shape, dtype)} compiled for "
+                f"{device.device_kind} without a Mosaic kernel"
+            )
+        return prog
+
+    return _program(key, build)
+
+
+@jax.jit
+def _checksum(leaves) -> jax.Array:
+    return sum(jnp.sum(x.astype(jnp.float32)) for x in leaves)
+
+
+def checksum(out) -> float:
+    """f32 sum over every leaf of a kernel's output, reduced on its device:
+    the same program on the same operands gives the same value bit for bit."""
+    return float(_checksum(jax.tree_util.tree_leaves(out)))
 
 
 def max_abs_err(a, b) -> float:
@@ -425,5 +534,9 @@ __all__ = [
     "shape_sig",
     "config_sig",
     "interpret_default",
+    "published_shapes",
+    "operands",
+    "compiled",
+    "checksum",
     "max_abs_err",
 ]

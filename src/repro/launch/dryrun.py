@@ -26,7 +26,6 @@ import jax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.configs import get_arch, get_shape, token_batch_spec, ARCHS, SHAPES
-from repro.compat import compat_cost_analysis
 from repro.launch.mesh import make_production_mesh
 from repro.models.model import Model
 from repro.optim import adamw
@@ -146,7 +145,7 @@ def measure_costs(arch, shape_name: str, mesh, strategy_name, units: int) -> dic
         jfn, args, _ = build_cell(variant, shape_name, mesh, strategy_name)
         lowered = jfn.lower(*args)
     compiled = lowered.compile()
-    cost = compat_cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     text = compiled.as_text()
     coll = parse_collectives(text)
     return {
@@ -212,7 +211,7 @@ def run_cell(
     t_compile = time.perf_counter() - t0
 
     mem = compiled.memory_analysis()
-    cost = compat_cost_analysis(compiled)
+    cost = compiled.cost_analysis()
     coll = parse_collectives(compiled.as_text())
 
     shape = get_shape(shape_name)

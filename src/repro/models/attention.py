@@ -202,9 +202,7 @@ def _decode_attention_distributed(
         out = acc_g / jnp.maximum(l_g, 1e-37)[..., None]
         return out[:, None].astype(q.dtype)
 
-    from repro.compat import compat_shard_map
-
-    fn = compat_shard_map(
+    fn = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -215,6 +213,7 @@ def _decode_attention_distributed(
             P(bspec),
         ),
         out_specs=P(bspec, None, None, None),
+        check_vma=False,
     )
     return fn(q, k_cache, v_cache, cache_positions, pos)
 

@@ -10,6 +10,7 @@ data-parallel axis crossing the inter-pod DCI links.
 """
 from __future__ import annotations
 
+import threading
 from dataclasses import dataclass, replace
 from typing import Optional, Union
 
@@ -243,7 +244,10 @@ def param_sharding_tree(specs, strategy: Strategy, mesh: Mesh):
 # ---------------------------------------------------------------------------
 
 
-class _Ctx:
+class _Ctx(threading.local):
+    """Per thread: broker executor threads trace steps for different
+    devices' meshes at the same time."""
+
     rules: Optional[dict[str, AxisVal]] = None
     mesh: Optional[Mesh] = None
     flash_decode: bool = False

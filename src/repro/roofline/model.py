@@ -1,9 +1,9 @@
 """Three-term roofline from a compiled dry-run artifact.
 
-Hardware constants (TPU v5e target):
-    peak bf16 compute : 197 TFLOP/s per chip
-    HBM bandwidth     : 819 GB/s per chip
-    ICI link bandwidth: ~50 GB/s per link per chip
+Hardware constants come from one table keyed by the chip's ``device_kind``
+(:data:`PEAKS`); a chip that is not in it is an error, never a default.
+The dry-run models the production v5e mesh, so a :class:`Roofline` prices
+its terms at ``peaks(V5E)``.
 
 Terms (seconds, per step):
     compute    = HLO_FLOPs_per_chip / peak
@@ -17,10 +17,36 @@ remat/redundancy waste.
 from __future__ import annotations
 
 from dataclasses import dataclass
+from typing import ClassVar
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
+
+@dataclass(frozen=True)
+class ChipPeaks:
+    """Published per-chip peaks."""
+
+    flops: float  # bf16 FLOP/s
+    hbm_bw: float  # HBM bytes/s
+    link_bw: float  # ICI bytes/s per link
+
+
+V5E = "TPU v5 lite"  # jax Device.device_kind of a TPU v5e chip
+
+# Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB of HBM at
+# 819 GB/s, 1,600 Gbit/s of chip-to-chip interconnect (4 links x 50 GB/s)
+PEAKS = {
+    V5E: ChipPeaks(flops=197e12, hbm_bw=819e9, link_bw=50e9),
+}
+
+
+def peaks(device_kind: str) -> ChipPeaks:
+    """Peaks of the chip named by ``device_kind``; unknown chips raise."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(
+            f"no published peaks for device kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        ) from None
 
 
 @dataclass
@@ -34,24 +60,25 @@ class Roofline:
     collective_bytes_per_chip: float
     model_flops_total: float
     hbm_bytes_est_per_chip: float = 0.0
+    chip: ClassVar[ChipPeaks] = peaks(V5E)
 
     @property
     def t_compute(self) -> float:
-        return self.flops_per_chip / PEAK_FLOPS
+        return self.flops_per_chip / self.chip.flops
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_per_chip / HBM_BW
+        return self.bytes_per_chip / self.chip.hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes_per_chip / LINK_BW
+        return self.collective_bytes_per_chip / self.chip.link_bw
 
     @property
     def t_memory_est(self) -> float:
         """Fusion-aware HBM-traffic estimate (see roofline/hlo.py); the raw
         cost_analysis bytes (t_memory) are an unfused upper bound on CPU."""
-        return self.hbm_bytes_est_per_chip / HBM_BW
+        return self.hbm_bytes_est_per_chip / self.chip.hbm_bw
 
     @property
     def bottleneck_est(self) -> str:
@@ -70,7 +97,7 @@ class Roofline:
     def mfu_est(self) -> float:
         """MODEL_FLOPS / (chips * peak * step_est): the roofline fraction with
         the fusion-aware memory term."""
-        denom = self.n_chips * PEAK_FLOPS * self.step_time_est
+        denom = self.n_chips * self.chip.flops * self.step_time_est
         return self.model_flops_total / denom if denom else 0.0
 
     @property
@@ -97,7 +124,7 @@ class Roofline:
     def mfu_upper_bound(self) -> float:
         """MODEL_FLOPS / (chips * peak * step_lower_bound): the roofline
         fraction achievable if the step ran exactly at its dominant term."""
-        denom = self.n_chips * PEAK_FLOPS * self.step_time_lower_bound
+        denom = self.n_chips * self.chip.flops * self.step_time_lower_bound
         return self.model_flops_total / denom if denom else 0.0
 
     def row(self) -> dict:

@@ -150,9 +150,7 @@ def make_compressed_train_step(
         # params replicated over dp (their model-axis sharding is auto-handled)
         batch_specs = jax.tree.map(lambda _: P(dp if len(dp) > 1 else dp[0]), batch)
         rep = P()
-        from repro.compat import compat_shard_map
-
-        grads, comp_state, metrics = compat_shard_map(
+        grads, comp_state, metrics = jax.shard_map(
             shard_body,
             mesh=mesh,
             in_specs=(jax.tree.map(lambda _: rep, params), batch_specs, jax.tree.map(lambda _: rep, comp_state)),
@@ -161,6 +159,7 @@ def make_compressed_train_step(
                 jax.tree.map(lambda _: rep, comp_state),
                 jax.tree.map(lambda _: rep, metrics_struct(model)),
             ),
+            check_vma=False,
         )(params, batch, comp_state)
         params, opt_state, opt_metrics = adamw.apply_updates(
             opt_cfg, params, grads, opt_state

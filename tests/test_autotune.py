@@ -115,6 +115,18 @@ def test_same_seed_runs_produce_byte_identical_payloads():
     assert b'"seed":7' in payloads[0]
 
 
+def test_cache_keys_by_device_kind_and_model_prices_named_chip():
+    import jax
+
+    from repro.roofline.model import V5E, peaks
+
+    key = _model_tuner().cache_key(*DEMO, "float32")
+    assert key.split(":")[2] == jax.devices()[0].device_kind.replace(" ", "-")
+    assert peaks(V5E).flops == 197e12 and peaks(V5E).hbm_bw == 819e9
+    with pytest.raises(KeyError):  # no default peak for a chip not in the table
+        peaks(jax.devices()[0].device_kind + " (not in the table)")
+
+
 def test_winner_registers_as_pinned_shared_dataset():
     name, shape = DEMO
     registry = DatasetRegistry()

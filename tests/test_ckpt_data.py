@@ -2,7 +2,6 @@
 import os
 
 import jax
-from repro.compat import compat_make_mesh
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -91,7 +90,7 @@ def test_train_restart_equivalence(tmp_path):
     cfg = get_arch("llama3-8b").reduced()
     model = Model(cfg)
     ocfg = adamw.AdamWConfig(peak_lr=1e-3, warmup_steps=2, total_steps=10)
-    mesh = compat_make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(jax.sharding.AxisType.Auto,))
     ts = jax.jit(step_lib.make_train_step(model, STRATEGIES["tp"], mesh, ocfg))
     dc = DataConfig(vocab_size=cfg.vocab_size, seq_len=16, global_batch=4)
 

@@ -7,7 +7,6 @@ import textwrap
 import numpy as np
 
 import jax
-from repro.compat import compat_make_mesh
 import jax.numpy as jnp
 
 from repro.optim import compression as C
@@ -33,11 +32,10 @@ _SUBPROC = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
-    from jax.sharding import PartitionSpec as P
-    from repro.compat import compat_make_mesh, compat_shard_map
+    from jax.sharding import AxisType, PartitionSpec as P
     from repro.optim import compression as C
 
-    mesh = compat_make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
     rng = np.random.default_rng(0)
     shape = (37, 53)
     xs = rng.normal(size=(8,) + shape).astype(np.float32)
@@ -47,7 +45,9 @@ _SUBPROC = textwrap.dedent("""
     def f(x_local, st):
         return C.compressed_mean(x_local[0], st, "data")
 
-    fm = compat_shard_map(f, mesh=mesh, in_specs=(P("data"), P()), out_specs=P())
+    fm = jax.shard_map(
+        f, mesh=mesh, in_specs=(P("data"), P()), out_specs=P(), check_vma=False
+    )
     got, st = jax.jit(fm)(jnp.asarray(xs), state)
     one_shot = float(np.max(np.abs(np.asarray(got) - true_mean)) / np.max(np.abs(true_mean)))
     assert one_shot < 0.05, one_shot

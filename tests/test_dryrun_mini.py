@@ -11,15 +11,14 @@ _SUBPROC = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax
-    from repro.compat import compat_cost_analysis, compat_make_mesh
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
     from repro.configs import get_arch, get_shape, token_batch_spec
     from repro.models.model import Model
     from repro.optim import adamw
     from repro.parallel.sharding import STRATEGIES
     from repro.train import step as step_lib
 
-    mesh = compat_make_mesh((2, 4), ("data", "model"))
+    mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
 
     for arch_name in ("llama3-8b", "falcon-mamba-7b", "grok-1-314b"):
         arch = get_arch(arch_name).reduced().replace(
@@ -47,7 +46,7 @@ _SUBPROC = textwrap.dedent("""
             donate_argnums=(0, 1))
         compiled = jfn.lower(params, opt, batch_specs).compile()
         mem = compiled.memory_analysis()
-        cost = compat_cost_analysis(compiled)
+        cost = compiled.cost_analysis()
         assert cost["flops"] > 0
         print("MINI_DRYRUN_OK", arch_name, int(cost["flops"]))
 """)

@@ -11,8 +11,7 @@ _SUBPROC = textwrap.dedent("""
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import dataclasses
     import jax, jax.numpy as jnp, numpy as np
-    from repro.compat import compat_make_mesh
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
     from repro.configs import get_arch
     from repro.models.model import Model
     from repro.parallel import sharding as sh
@@ -22,7 +21,7 @@ _SUBPROC = textwrap.dedent("""
         cfg = get_arch(arch_name).reduced().replace(n_kv_heads=kv)
         model = Model(cfg)
         params = model.init(jax.random.key(0))
-        mesh = compat_make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
         rng = np.random.default_rng(0)
         B, L = 4, 16
         toks = jnp.asarray(rng.integers(0, cfg.vocab_size, (B, L + 1)), jnp.int32)

@@ -10,10 +10,17 @@ kernel payloads completes with ZERO failed tasks under the PR-6 correlated
 fault schedule."""
 from __future__ import annotations
 
+import os
+import subprocess
+import sys
+import textwrap
+
+import jax
 import pytest
 
 from repro.core import Hydra, ProviderSpec, Task, TaskState
 from repro.core.events import EventBus
+from repro.core.provider import ValidationError
 from repro.core.managers.compute import KERNEL_RUNTIME
 from repro.core.staging import DatasetRegistry
 from repro.ckpt.checkpoint import TaskCheckpointer
@@ -31,7 +38,7 @@ from conftest import wait_until
 
 def test_kernel_runtime_executes_and_advances_progress():
     task = Task(kind="kernel", payload={"kernel": "moe_gmm", "reps": 2, "seed": 1})
-    result = KERNEL_RUNTIME.run(task)
+    result = KERNEL_RUNTIME.run(task, jax.devices()[0])
     assert result["kernel"] == "moe_gmm"
     assert result["reps"] == 2 and result["skipped_reps"] == 0
     assert result["kernel_s"] > 0
@@ -48,7 +55,7 @@ def test_kernel_runtime_resume_skips_completed_reps():
     task = Task(kind="kernel", payload={"kernel": "rglru_scan", "reps": 4})
     task.progress_frac = 0.5  # two of four reps completed before the kill
     task.kernel_done_s = 0.125
-    result = KERNEL_RUNTIME.run(task)
+    result = KERNEL_RUNTIME.run(task, jax.devices()[0])
     assert result["skipped_reps"] == 2
     assert result["reps"] == 4
     assert task.progress_frac == 1.0
@@ -68,7 +75,7 @@ def test_kernel_runtime_honors_explicit_payload_config():
             "config": {"block_d": 32},
         },
     )
-    result = KERNEL_RUNTIME.run(task)
+    result = KERNEL_RUNTIME.run(task, jax.devices()[0])
     assert result["config"] == "block_d=32"
     assert result["sig"] == kreg.shape_sig(shape, "float32")
 
@@ -132,6 +139,87 @@ def test_broker_executes_one_task_per_registered_kernel(tmp_path):
     exec_events = [e for e in h.events.events() if e.name == "kernel.exec"]
     assert len(exec_events) == len(tasks)
     h.shutdown(wait=True)
+
+
+@pytest.mark.parametrize("name", sorted(kreg.KERNELS))
+@pytest.mark.parametrize("connector", ["caas", "pilot"])
+def test_kernel_task_names_its_device_and_checksums_its_output(tmp_path, name, connector):
+    """The result says where the kernel ran (a device of the provider's
+    slice) and carries a checksum of its output that agrees with the
+    pure-jnp reference on the same seeded operands."""
+    h = Hydra(pod_store="memory", streaming=True, batch_window=0.0, workdir=str(tmp_path))
+    handle = h.register_provider(ProviderSpec(name="p", connector=connector, concurrency=1))
+    kdef = kreg.get_kernel(name)
+    task = Task(kind="kernel", payload={"kernel": name, "seed": 5})
+    h.dispatch([task])
+    assert wait_until(task.done, timeout=60.0)
+    h.shutdown(wait=True)
+    result = task.result()
+    assert result["device"] in [d.id for d in handle.devices]
+    assert result["platform"] == handle.devices[0].platform
+    shape = dict(kdef.tiny_shape)
+    want = kreg.checksum(kdef.ref(shape, kdef.make_args(shape, "float32", 5)))
+    assert result["checksum"] == pytest.approx(want, rel=1e-5, abs=1e-4)
+
+
+@pytest.mark.parametrize(
+    "offset,n_devices", [(1, 1), (0, 2), (-1, 1), (len(jax.devices()), 1)]
+)
+def test_missing_device_slice_is_refused(tmp_path, offset, n_devices):
+    """A provider whose slice is not among the visible devices is an
+    error, not a silent wrap onto an existing device."""
+    h = Hydra(pod_store="memory", workdir=str(tmp_path))
+    with pytest.raises(ValidationError):
+        h.register_provider(ProviderSpec(name="p", device_offset=offset, n_devices=n_devices))
+    h.register_provider(ProviderSpec(name="q"))  # offset 0: the shared device 0
+    h.shutdown(wait=True)
+
+
+_FOUR_DEVICES = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import concurrent.futures
+    from repro.core import Hydra, ProviderSpec, Task
+    from repro.kernels import registry as kreg
+
+    h = Hydra(pod_store="memory", streaming=True, batch_window=0.0)
+    for i in range(4):
+        connector = "pilot" if i == 3 else "caas"
+        h.register_provider(ProviderSpec(
+            name=f"chip{i}", connector=connector, device_offset=i, concurrency=2))
+    tasks = [
+        Task(kind="kernel", payload={"kernel": "rglru_scan", "seed": i % 2})
+        for i in range(16)
+    ]
+    h.dispatch(tasks)
+    _, pending = concurrent.futures.wait(tasks, timeout=120)
+    h.shutdown(wait=True)
+    assert not pending
+    seen = set()
+    for t in tasks:
+        r = t.result()
+        assert r["device"] == int(t.provider[-1]), (t.provider, r["device"])
+        seen.add(r["device"])
+    assert seen == {0, 1, 2, 3}, seen
+    by_seed = {}
+    for t in tasks:
+        by_seed.setdefault(t.payload["seed"], set()).add(t.result()["checksum"])
+    assert all(len(v) == 1 for v in by_seed.values()), by_seed
+    print("FOUR_DEVICES_OK", sorted(seen))
+""")
+
+
+def test_four_providers_run_on_four_devices():
+    """Four one-device providers on four (virtual CPU) devices: each task
+    runs on its provider's device, every device does work, and a seed's
+    checksum is the same on every device."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    env["JAX_PLATFORMS"] = "cpu"
+    out = subprocess.run(
+        [sys.executable, "-c", _FOUR_DEVICES], env=env, capture_output=True, text=True, timeout=300
+    )
+    assert "FOUR_DEVICES_OK" in out.stdout, out.stderr[-3000:]
 
 
 def test_broker_kernel_tasks_consult_tuned_cache_under_gate(tmp_path, monkeypatch):

@@ -70,6 +70,21 @@ def test_selective_scan_sweep(B, ck, di, N, block_d):
     np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), rtol=1e-5, atol=1e-5)
 
 
+def test_selective_scan_bf16_input():
+    """bf16 x (the dtype models feed) is read in whole packed slabs."""
+    B, ck, di, N = 1, 48, 256, 16
+    x = jnp.asarray(RNG.normal(size=(B, ck, di)), jnp.bfloat16)
+    dt = jnp.asarray(RNG.uniform(0.001, 0.1, (B, ck, di)), jnp.float32)
+    bm = jnp.asarray(RNG.normal(size=(B, ck, N)), jnp.float32)
+    cm = jnp.asarray(RNG.normal(size=(B, ck, N)), jnp.float32)
+    a = -jnp.asarray(RNG.uniform(0.5, 2.0, (di, N)), jnp.float32)
+    h0 = jnp.asarray(RNG.normal(size=(B, di, N)), jnp.float32)
+    y1, h1 = ops.selective_scan_chunk(x, dt, bm, cm, a, h0, block_d=128)
+    y2, h2 = ref.selective_scan_chunk_ref(x, dt, bm, cm, a, h0)
+    np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), rtol=1e-5, atol=1e-5)
+
+
 def test_selective_scan_chains_chunks():
     """Two chunks chained via h0 == one double-length chunk."""
     B, ck, di, N = 1, 16, 64, 8
@@ -86,7 +101,16 @@ def test_selective_scan_chains_chunks():
     np.testing.assert_allclose(np.asarray(h_full), np.asarray(h2), rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("B,L,dr,block_d", [(1, 32, 128, 64), (2, 64, 256, 128), (2, 128, 512, 512)])
+@pytest.mark.parametrize(
+    "B,L,dr,block_d",
+    [
+        (1, 32, 128, 64),
+        (2, 64, 256, 128),
+        (2, 128, 512, 512),
+        (1, 512, 256, 128),  # two 256-step sequence blocks carry the state
+        (2, 520, 128, 128),  # 104-step blocks: L is not a multiple of 256
+    ],
+)
 def test_rglru_sweep(B, L, dr, block_d):
     la = -jnp.asarray(RNG.uniform(0.01, 1.0, (B, L, dr)), jnp.float32)
     gx = jnp.asarray(RNG.normal(size=(B, L, dr)), jnp.float32)
@@ -123,3 +147,25 @@ def test_model_path_with_pallas_matches_xla():
     y_xla = ssm.mamba_block(cfg, x, block, use_pallas=False)
     y_pallas = ssm.mamba_block(cfg, x, block, use_pallas=True)
     np.testing.assert_allclose(np.asarray(y_xla), np.asarray(y_pallas), rtol=2e-4, atol=2e-4)
+
+
+def test_rglru_seq_with_pallas_matches_xla():
+    """RecurrentGemma's recurrence via the Pallas kernel == the XLA scan,
+    over a sequence long enough for several sequence blocks."""
+    from repro.models import rglru
+
+    B, L, nb, bd = 2, 512, 2, 128
+    dr = nb * bd
+    p = {
+        "w_rec_gate": jnp.asarray(RNG.normal(size=(nb, bd, bd)) / bd**0.5, jnp.float32),
+        "b_rec_gate": jnp.zeros((dr,), jnp.float32),
+        "w_in_gate": jnp.asarray(RNG.normal(size=(nb, bd, bd)) / bd**0.5, jnp.float32),
+        "b_in_gate": jnp.zeros((dr,), jnp.float32),
+        "lam": jnp.asarray(RNG.normal(size=(dr,)), jnp.float32),
+    }
+    u = jnp.asarray(RNG.normal(size=(B, L, dr)), jnp.float32)
+    h0 = jnp.asarray(RNG.normal(size=(B, dr)), jnp.float32)
+    y_xla, h_xla = rglru.rglru_seq(p, u, h0, use_pallas=False)
+    y_pallas, h_pallas = rglru.rglru_seq(p, u, h0, use_pallas=True)
+    np.testing.assert_allclose(np.asarray(y_xla), np.asarray(y_pallas), rtol=2e-4, atol=2e-4)
+    np.testing.assert_allclose(np.asarray(h_xla), np.asarray(h_pallas), rtol=2e-4, atol=2e-4)

@@ -20,7 +20,7 @@ import random
 import threading
 
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import Hydra, ProviderSpec, Task
 from repro.core.ledger import CapacityLedger, LedgerDivergence

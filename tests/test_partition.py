@@ -1,6 +1,6 @@
 """Partitioning invariants (hypothesis): every task in exactly one pod,
 capacity respected, SCPP/MCPP pod counts correct."""
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.partition import partition
 from repro.core.task import Resources, Task

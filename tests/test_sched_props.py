@@ -15,7 +15,7 @@ hundreds of multi-second sleep tasks in real milliseconds.
 import random
 
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import Hydra, ProviderSpec, Task, TaskState, Workflow, WorkflowManager
 from repro.runtime.clock import virtual_time

@@ -1,7 +1,6 @@
 """Sharding rule resolution: strategies, divisibility drops, spill targets."""
 import jax
-from repro.compat import compat_make_mesh
-from jax.sharding import PartitionSpec as P
+from jax.sharding import AxisType, PartitionSpec as P
 
 from repro.configs import get_arch
 from repro.models.model import Model
@@ -58,7 +57,7 @@ def test_default_strategy_by_size():
 
 
 def test_param_pspec_tree_covers_every_leaf():
-    mesh = compat_make_mesh((1, 1), ("data", "model"))
+    mesh = jax.make_mesh((1, 1), ("data", "model"), axis_types=(AxisType.Auto,) * 2)
     for name in ("llama3-8b", "arctic-480b", "falcon-mamba-7b", "recurrentgemma-2b"):
         arch = get_arch(name)
         model = Model(arch)

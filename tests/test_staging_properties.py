@@ -7,18 +7,14 @@ disagrees with per-site ``transfer_cost_s``, the gravity policy silently
 places against different costs inside a ``bind_bulk`` than outside one.
 Swept here over randomized (inputs, targets) sets — including unknown and
 replica-less datasets — both directly and through ``Policy.data_costs``
-inside and outside ``bulk_scope()``.
-
-Uses the deterministic hypothesis shim (tests/_hypothesis_compat.py): the
-real library drives the sweep when installed, a bounded example product
-otherwise."""
+inside and outside ``bulk_scope()``."""
 from __future__ import annotations
 
 from repro.core.policy import make_policy
 from repro.core.staging import StagingService
 from repro.core.task import Task
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 SITES = ("jet2", "chi", "bridges2", "frontier")
 DATASETS = (

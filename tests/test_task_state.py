@@ -1,6 +1,6 @@
 """Task state machine: legal transitions, idempotent completion, tracing."""
 import pytest
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.task import (
     FINAL_STATES,

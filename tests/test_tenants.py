@@ -18,7 +18,7 @@ from repro.core.admission import (
 from repro.core.policy import apportion_budget
 from repro.runtime.clock import virtual_time
 
-from _hypothesis_compat import given, settings, st
+from hypothesis import given, settings, strategies as st
 from conftest import wait_until
 
 
