@@ -46,7 +46,7 @@ from repro.core.provider import ProviderHandle, ProviderProxy, ProviderSpec
 from repro.core.staging import LinkModel, StagingService
 from repro.core.task import Task, TaskState
 from repro.runtime.clock import guard_wait
-from repro.runtime.tracing import Metrics, Trace, compute_metrics, now
+from repro.runtime.tracing import Metrics, Trace, compute_metrics, now, span, trace_gc
 
 
 class Submission:
@@ -133,6 +133,7 @@ class Hydra:
         staging_mirror_outputs: bool = False,
         tenants: Optional[list[TenantSpec]] = None,
     ):
+        trace_gc()  # collections stall every thread: name them on a profile
         self.workdir = workdir or tempfile.mkdtemp(prefix="hydra_")
         os.makedirs(self.workdir, exist_ok=True)
         self.proxy = ProviderProxy()
@@ -938,12 +939,12 @@ class Hydra:
         sub.batch_id = batch_id
 
         # -- bind (late: provider/group health is read NOW, at dispatch) ---
-        rt.add("bind_start")
-        targets = self.proxy.bind_targets()
-        if not targets:
-            raise RuntimeError("no healthy providers registered")
-        by_provider: dict[str, list[Task]] = {}
-        names = self.policy.bind_bulk(tasks, targets)
+        with span("broker.bind", rt, "bind_start", batch=batch_id, n=len(tasks)):
+            targets = self.proxy.bind_targets()
+            if not targets:
+                raise RuntimeError("no healthy providers registered")
+            by_provider: dict[str, list[Task]] = {}
+            names = self.policy.bind_bulk(tasks, targets)
         try:
             for t, name in zip(tasks, names):
                 t.provider = name
@@ -953,24 +954,24 @@ class Hydra:
             rt.add("bind_done")
 
             # -- partition ---------------------------------------------------
-            rt.add("partition_start")
-            pods: list[Pod] = []
-            for name, ts in by_provider.items():
-                ppods = partition(ts, name, model=model, tasks_per_pod=tpp)
-                for p in ppods:
-                    p.batch_id = batch_id
-                    for t in p.tasks:
-                        t.advance(TaskState.PARTITIONED)
-                pods.extend(ppods)
-            sub.pods.extend(pods)
-            with self._lock:
-                self.n_pods_total += len(pods)
+            with span("broker.partition", rt, "partition_start", batch=batch_id):
+                pods: list[Pod] = []
+                for name, ts in by_provider.items():
+                    ppods = partition(ts, name, model=model, tasks_per_pod=tpp)
+                    for p in ppods:
+                        p.batch_id = batch_id
+                        for t in p.tasks:
+                            t.advance(TaskState.PARTITIONED)
+                    pods.extend(ppods)
+                sub.pods.extend(pods)
+                with self._lock:
+                    self.n_pods_total += len(pods)
             rt.add("partition_done")
 
             # -- serialize ---------------------------------------------------
-            rt.add("serialize_start")
-            for p in pods:
-                self.store.serialize(p)
+            with span("broker.serialize", rt, "serialize_start", batch=batch_id, pods=len(pods)):
+                for p in pods:
+                    self.store.serialize(p)
             rt.add("serialize_done")
         except BaseException as e:
             # nothing reached a provider yet: fully reverse the batch's load
@@ -1012,11 +1013,12 @@ class Hydra:
         # loop, not a hop
         items = list(per_provider.items())
         n_chunks = max(1, min(len(items), self._dispatch_workers))
-        futs = [
-            self._dispatch.submit(self._submit_chunk, items[i::n_chunks])
-            for i in range(n_chunks)
-        ]
-        futures_wait(futs)
+        with span("broker.submit", batch=batch_id, pods=len(pods)):
+            futs = [
+                self._dispatch.submit(self._submit_chunk, items[i::n_chunks])
+                for i in range(n_chunks)
+            ]
+            futures_wait(futs)
         for f in futs:
             exc = f.exception()
             if exc is not None and not isinstance(exc, ProviderDown):

@@ -37,7 +37,7 @@ from repro.core.policy import NoEligibleProvider, apportion_budget
 from repro.core.staging import StagingError
 from repro.core.task import SLO_CLASSES, Task, TaskState
 from repro.runtime.clock import get_clock
-from repro.runtime.tracing import Counter, Trace
+from repro.runtime.tracing import Counter, Trace, span
 
 if TYPE_CHECKING:  # pragma: no cover
     from repro.core.broker import Hydra
@@ -160,7 +160,7 @@ class StreamingDispatcher:
         """Feed ready tasks (deps satisfied) from any workflow or caller."""
         if not tasks:
             return
-        with self._lock:
+        with span("dispatch.enqueue", [t.trace for t in tasks], "queued", n=len(tasks)), self._lock:
             added = False
             for t in tasks:
                 if t.uid in self._queued:
@@ -570,12 +570,14 @@ class StreamingDispatcher:
     def _dispatch(self, batch: list[Task]) -> None:
         batch_id = _batch_ids.next()
         try:
-            sub = self.broker.submit(
-                batch,
-                partitioning=self.broker.partitioning,
-                tasks_per_pod=self.broker.tasks_per_pod,
-                batch_id=batch_id,
-            )
+            with span("dispatch.batch", [t.trace for t in batch], "batched", batch=batch_id, n=len(batch)) as s:
+                sub = self.broker.submit(
+                    batch,
+                    partitioning=self.broker.partitioning,
+                    tasks_per_pod=self.broker.tasks_per_pod,
+                    batch_id=batch_id,
+                )
+                s.set_metadata(pods=len(sub.pods))
         except NoEligibleProvider:
             # late binding found an unplaceable task (bind_bulk validates
             # eligibility before any stateful binding, so no load accounting
@@ -615,7 +617,6 @@ class StreamingDispatcher:
         # exp9/exp11 hot path while the view still derives the task total
         self.broker.events.emit("dispatch.batch", n=len(batch))
         self._consecutive_failures = 0
-        self.trace.add(f"batch:{batch_id}:{len(batch)}:{len(sub.pods)}")
 
     def _retry(self, batch: list[Task], exc: Optional[BaseException] = None) -> None:
         """Transient dispatch failure (e.g. every provider momentarily

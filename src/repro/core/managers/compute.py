@@ -22,10 +22,18 @@ from repro.core.pod import Pod
 from repro.core.provider import ProviderHandle
 from repro.core.task import Task, TaskState
 from repro.runtime.clock import get_clock
+from repro.runtime.tracing import span
 
 
 class ProviderDown(RuntimeError):
     pass
+
+
+def exec_span(task: Task, provider: str):
+    """A task's run on its provider: stamps ``exec_start``, spans
+    ``hydra.exec.task`` (shared by the CaaS and pilot managers)."""
+    kernel = (task.payload or {}).get("kernel") if task.kind == "kernel" else task.kind
+    return span("exec.task", task.trace, "exec_start", uid=task.uid, provider=provider, kernel=kernel)
 
 
 class Preempted(RuntimeError):
@@ -166,19 +174,28 @@ class KernelRuntime:
         seed = int(spec.get("seed", 0))
         config = spec.get("config") or tuned_config(kdef.name, shape, dtype) or kdef.defaults(shape)
         program = kreg.compiled(kdef, shape, dtype, config, device)
-        args = kreg.operands(kdef, shape, dtype, seed, device)
+        with span("kernel.operands", uid=task.uid):
+            args = kreg.operands(kdef, shape, dtype, seed, device)
         done = min(reps, int(round(task.progress_frac * reps)))
         out = None
         t0 = _time.perf_counter()
         for r in range(done, reps):
-            out = program(*args)
-            jax.block_until_ready(out)
+            # launch: the host issues the kernel; sync: the chip runs the
+            # operand build and the kernel, behind whatever it already holds
+            with span("kernel.launch", uid=task.uid, rep=r):
+                out = program(*args)
+            task.trace.add("launched")
+            with span("kernel.sync", uid=task.uid, rep=r):
+                jax.block_until_ready(out)
+            task.trace.add("synced")
             # completed-rep boundary: durable progress the checkpointer can
             # capture without losing more than the rep in flight
             task.kernel_done_s += _time.perf_counter() - t0
             t0 = _time.perf_counter()
             task.progress_frac = (r + 1) / reps
         kernel_s = task.kernel_done_s
+        with span("kernel.checksum", uid=task.uid):
+            total = None if out is None else kreg.checksum(out)
         # lifetime totals (reps survive preempt/resume cycles): the broker
         # emits ONE kernel.exec per completed task, so execs reconcile with
         # completed-task counts and reps/seconds with total work performed
@@ -197,7 +214,7 @@ class KernelRuntime:
             "kernel_s": kernel_s,
             "device": device.id,
             "platform": device.platform,
-            "checksum": None if out is None else kreg.checksum(out),
+            "checksum": total,
         }
 
 
@@ -273,27 +290,29 @@ class CaaSManager:
 
     # -- execution -----------------------------------------------------
     def _run_pod(self, pod: Pod):
-        pod.trace.add("env_setup_start")
-        if self.spec.env_setup_s:
-            get_clock().sleep(self.spec.env_setup_s * (1 if pod.model != "scpp" else 1.0))
-        pod.trace.add("env_setup_done")
-        try:
-            for t in pod.tasks:
-                if self.down:
-                    # fail the remaining tasks so the broker re-binds them
-                    for rest in pod.tasks:
-                        if (
-                            not rest.final
-                            and rest.provider == self.handle.name
-                            and rest.mark_failed(ProviderDown(self.handle.name))
-                            and self.on_task_done
-                        ):
-                            self.on_task_done(rest, self.handle.name, failed=True)
-                    return
-                self._run_task(t)
-        finally:
-            pod.trace.add("env_teardown_start")
-            pod.trace.add("env_teardown_done")
+        # an executor thread took the pod: every task in it has its slot
+        with span("exec.pod", [t.trace for t in pod.tasks], "slot", pod=pod.uid, provider=self.handle.name):
+            pod.trace.add("env_setup_start")
+            if self.spec.env_setup_s:
+                get_clock().sleep(self.spec.env_setup_s * (1 if pod.model != "scpp" else 1.0))
+            pod.trace.add("env_setup_done")
+            try:
+                for t in pod.tasks:
+                    if self.down:
+                        # fail the remaining tasks so the broker re-binds them
+                        for rest in pod.tasks:
+                            if (
+                                not rest.final
+                                and rest.provider == self.handle.name
+                                and rest.mark_failed(ProviderDown(self.handle.name))
+                                and self.on_task_done
+                            ):
+                                self.on_task_done(rest, self.handle.name, failed=True)
+                        return
+                    self._run_task(t)
+            finally:
+                pod.trace.add("env_teardown_start")
+                pod.trace.add("env_teardown_done")
 
     def _run_task(self, task: Task):
         # canceled, speculatively completed elsewhere, or re-bound away:
@@ -302,9 +321,9 @@ class CaaSManager:
             if self.on_task_skipped:
                 self.on_task_skipped(task, self.handle.name)
             return
-        task.trace.add("exec_start")
         try:
-            result = self._execute(task)
+            with exec_span(task, self.handle.name):
+                result = self._execute(task)
         except Exception as e:
             if task.mark_failed(e):
                 with self._lock:

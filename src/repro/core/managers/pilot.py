@@ -11,7 +11,7 @@ import queue
 import threading
 from typing import Callable, Optional
 
-from repro.core.managers.compute import COMPUTE_RUNTIME, KERNEL_RUNTIME, ProviderDown
+from repro.core.managers.compute import COMPUTE_RUNTIME, KERNEL_RUNTIME, ProviderDown, exec_span
 from repro.core.pod import Pod
 from repro.core.provider import ProviderHandle
 from repro.core.task import Task, TaskState
@@ -109,6 +109,7 @@ class PilotManager:
             if item is None:
                 return
             task, pod = item
+            task.trace.add("slot")  # a worker of the standing pilot took it
             if self.down:
                 if (
                     task.provider == self.handle.name
@@ -127,21 +128,21 @@ class PilotManager:
             if self.on_task_skipped:
                 self.on_task_skipped(task, self.handle.name)
             return
-        task.trace.add("exec_start")
         try:
-            if task.kind == "noop":
-                result = None
-            elif task.kind == "sleep":
-                get_clock().sleep(task.duration)
-                result = None
-            elif task.kind == "callable":
-                result = task.fn() if task.fn else None
-            elif task.kind == "compute":
-                result = COMPUTE_RUNTIME.run(task, self.handle.next_device())
-            elif task.kind == "kernel":
-                result = KERNEL_RUNTIME.run(task, self.handle.next_device())
-            else:
-                raise ValueError(task.kind)
+            with exec_span(task, self.handle.name):
+                if task.kind == "noop":
+                    result = None
+                elif task.kind == "sleep":
+                    get_clock().sleep(task.duration)
+                    result = None
+                elif task.kind == "callable":
+                    result = task.fn() if task.fn else None
+                elif task.kind == "compute":
+                    result = COMPUTE_RUNTIME.run(task, self.handle.next_device())
+                elif task.kind == "kernel":
+                    result = KERNEL_RUNTIME.run(task, self.handle.next_device())
+                else:
+                    raise ValueError(task.kind)
         except Exception as e:
             if task.mark_failed(e):
                 with self._stats_lock:

@@ -138,4 +138,5 @@ def flash_attention(
             pltpu.VMEM((block_q, hd), jnp.float32),  # output accumulator
         ],
         interpret=interpret,
+        name="flash_attention",
     )(q, k, v)

@@ -68,4 +68,5 @@ def moe_gmm(
         out_shape=jax.ShapeDtypeStruct((E, C, F), x.dtype),
         scratch_shapes=[pltpu.VMEM((block_c, block_f), jnp.float32)],
         interpret=interpret,
+        name="moe_gmm",
     )(x, w)

@@ -472,12 +472,20 @@ def _program(key: tuple, build: Callable[[], Any]):
         return prog
 
 
+def _named(fn: Callable, name: str) -> Callable:
+    """``fn`` under ``name``: jit names the program, its host events and its
+    device ops after the function, so a trace reads ``moe_gmm``, not
+    ``_lambda_``."""
+    fn.__name__ = fn.__qualname__ = name
+    return fn
+
+
 def operands(kdef: KernelDef, shape: dict, dtype: str, seed: int, device):
     """``make_args`` compiled for ``device``: the operands are built there,
     so the kernel call that takes them runs there too."""
     key = ("args", kdef.name, shape_sig(shape, dtype), device)
     make = _program(key, lambda: jax.jit(
-        lambda s: kdef.make_args(shape, dtype, s),
+        _named(lambda s: kdef.make_args(shape, dtype, s), f"{kdef.name}_operands"),
         out_shardings=SingleDeviceSharding(device),
     ))
     return make(jnp.int32(seed))
@@ -494,7 +502,8 @@ def compiled(kdef: KernelDef, shape: dict, dtype: str, config: dict, device):
         sharding = SingleDeviceSharding(device)
         avals = jax.eval_shape(lambda: kdef.make_args(shape, dtype, 0))
         specs = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding) for a in avals]
-        prog = jax.jit(lambda *a: kdef.call(shape, a, config, interpret)).lower(*specs).compile()
+        call = _named(lambda *a: kdef.call(shape, a, config, interpret), kdef.name)
+        prog = jax.jit(call).lower(*specs).compile()
         if not interpret and "tpu_custom_call" not in prog.as_text():
             raise RuntimeError(
                 f"{kdef.name} at {shape_sig(shape, dtype)} compiled for "

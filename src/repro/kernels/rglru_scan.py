@@ -102,5 +102,6 @@ def rglru_scan(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
+        name="rglru_scan",
     )(log_a, gx, h0.reshape(B, 1, dr))
     return y, h_last.reshape(B, dr)

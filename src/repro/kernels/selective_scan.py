@@ -107,5 +107,6 @@ def selective_scan_chunk(
             jax.ShapeDtypeStruct((B, N, di), jnp.float32),
         ],
         interpret=interpret,
+        name="selective_scan",
     )(x, dt, b, c, a.T, h0.swapaxes(1, 2))
     return y, h_last.swapaxes(1, 2)

@@ -15,14 +15,25 @@ WallClock, exact virtual instants under a VirtualClock.  Metrics are derived
 purely from traces, so they are platform- and workload-agnostic, exactly as
 in the paper, and scheduler tests can replay 10k-task scenarios in virtual
 time without distorting a single metric formula.
+
+``span`` puts the same stamps on the profiler's timeline: each layer
+boundary of the task path opens a ``jax.profiler.TraceAnnotation`` named
+``hydra.<stage>`` that carries ``t0``, the clock read its stamp took, so
+one offset (the span's start in ns minus ``t0`` x 1e9) maps every task
+stamp onto the trace beside the device ops (docs/OBSERVABILITY.md).  The
+annotations record only while a profiler runs; otherwise a span costs a
+``TraceMe`` check and its stamp.
 """
 from __future__ import annotations
 
+import gc
 import threading
 from dataclasses import dataclass, field
-from typing import Iterable, Optional
+from typing import Iterable, Optional, Union
 
-from repro.runtime.clock import now
+from jax.profiler import TraceAnnotation
+
+from repro.runtime.clock import get_clock, now
 
 
 @dataclass
@@ -52,6 +63,73 @@ class Trace:
         if t0 is None or t1 is None:
             return None
         return t1 - t0
+
+
+class _NoSpan:
+    """What ``span`` returns while no profiler runs: a shared no-op."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+    def set_metadata(self, **args) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
+_profiling = TraceAnnotation.is_enabled
+
+
+def span(name: str, trace: Union[Trace, Iterable[Trace], None] = None,
+         stamp: Optional[str] = None, **args):
+    """``with span("kernel.sync", uid=task.uid): ...`` marks a stage of the
+    task path as ``hydra.kernel.sync`` on the profiler's timeline, with
+    ``args`` and ``t0`` (the active clock as the span opens, read lock-free:
+    ``Clock.stamp``) as its stats.
+
+    With ``trace`` (a ``Trace``, or several: one per task of a batch) and
+    ``stamp``, the span's start is also stamped on each, with the one clock
+    read that is ``t0``: a stamp and its span share an instant exactly, and
+    stay virtual under a ``VirtualClock``.  ``set_metadata(**args)`` on the
+    span adds stats known only at its end (a batch's pod count).  Call it in
+    the ``with`` statement: the stamp is taken here, not on entry."""
+    t0 = None
+    if stamp is not None:
+        t0 = get_clock().stamp()
+        for tr in (trace,) if isinstance(trace, Trace) else trace:
+            tr.events.append((stamp, t0))
+    if _profiling():
+        return TraceAnnotation(f"hydra.{name}", t0=get_clock().stamp() if t0 is None else t0, **args)
+    return _NO_SPAN
+
+
+_gc_annotation: Optional[TraceAnnotation] = None
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    # a collection starts and stops on one thread and never overlaps
+    # another, so one slot holds the open annotation
+    global _gc_annotation
+    if phase == "start":
+        if _profiling():
+            _gc_annotation = TraceAnnotation(
+                "hydra.gc", t0=get_clock().stamp(), generation=info["generation"]
+            )
+            _gc_annotation.__enter__()
+    elif _gc_annotation is not None:
+        annotation, _gc_annotation = _gc_annotation, None
+        annotation.__exit__(None, None, None)
+
+
+def trace_gc() -> None:
+    """Marks every garbage collection of this process as a ``hydra.gc``
+    span (idempotent: one callback per process)."""
+    if _on_gc not in gc.callbacks:
+        gc.callbacks.append(_on_gc)
 
 
 # ---------------------------------------------------------------------------
@@ -86,23 +164,6 @@ class Metrics:
             "n_tasks": self.n_tasks,
             "n_pods": self.n_pods,
             **{f"phase_{k}_s": round(v, 6) for k, v in self.phases.items()},
-        }
-
-    def otel(self) -> dict:
-        """The same row under the OTel-style metric names the event bus
-        uses (core/events.py, docs/OBSERVABILITY.md), so run-level metrics
-        and log-derived counters share one namespace in exported JSON."""
-        return {
-            "hydra.run.ovh_s": round(self.ovh, 6),
-            "hydra.run.th_tasks_per_s": round(self.th, 2),
-            "hydra.run.tpt_s": round(self.tpt, 6),
-            "hydra.run.ttx_s": round(self.ttx, 6),
-            "hydra.run.n_tasks": self.n_tasks,
-            "hydra.run.n_pods": self.n_pods,
-            **{
-                f"hydra.run.phase.{k}_s": round(v, 6)
-                for k, v in self.phases.items()
-            },
         }
 
 
