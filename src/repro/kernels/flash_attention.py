@@ -1,21 +1,43 @@
 """Flash attention (forward) Pallas TPU kernel with GQA-aware KV indexing.
 
-TPU mapping of the paper-agnostic attention hot-spot:
-  * grid = (batch, q_heads, q_blocks, kv_blocks); the innermost kv dimension
-    executes sequentially on TPU, so the online-softmax running state lives in
-    VMEM scratch that persists across kv iterations.
-  * BlockSpecs tile Q/K/V into (block_q x head_dim) / (block_k x head_dim)
-    VMEM tiles; block sizes are multiples of 128 to keep the MXU matmuls
-    hardware-aligned.
-  * GQA: the K/V BlockSpec index_map folds the query head onto its KV head
-    (h -> h * n_kv // n_heads), so grouped heads read the same KV tile and
-    nothing is materialized H-wide in HBM (unlike the XLA path).
-  * causal: fully-masked kv blocks are skipped with pl.when - this is the
-    ~2x FLOP saving over the XLA blockwise path recorded in §Roofline.
+Grid and state
+  grid = (batch, q_heads, q_blocks, kv_blocks).  The kv axis runs in order
+  on a TPU core, so the online softmax's running max ``m``, denominator
+  ``l`` and output accumulator stay in float32 VMEM scratch across it, and
+  the output tile is written once, after the last kv block.  GQA: the K/V
+  index map folds query head h onto KV head h * KV // H, so the heads of a
+  group read the same K/V tiles and nothing is widened to H heads in HBM.
 
-Validated against ref.attention_ref in interpret mode (CPU container); the
-TPU target is v5e (16 MB VMEM: worst tile footprint here is
-2*(block_q + 2*block_k) * hd * 4B ~ 1.5 MB at the defaults).
+Operand dtype
+  q.k^T takes q and k in their own dtype and accumulates in float32: bf16
+  payloads run on the MXU's native bf16 path (each product is exact in
+  float32), float32 payloads stay float32.  The scores are scaled once, in
+  float32.  p is cast to v's dtype before p.v, which accumulates in float32
+  again.  m, l and the accumulator are float32.  The dtype comes from the
+  operands; there is no switch.
+
+Tiles
+  ``default_blocks`` is the one tile rule for every caller without an
+  explicit config (ops.flash_attention, registry defaults): block_q is the
+  largest of 512, 256 and 128 that divides the query length, block_k the
+  largest of 1024, 512, 256 and 128 that divides the key length, each else
+  the length itself.  At Grok-1's 8k context that is 512 x 1024: 16 x 8
+  blocks per head in place of 64 x 64 at 128 x 128.  Of the pairs timed on
+  a v5e there, 512 x 1024 was the fastest (PERF.md).
+
+Masked blocks
+  A kv block wholly outside the causal / window band is skipped with
+  ``pl.when``, and the K/V index map clamps it onto the nearest live block
+  of its q row.  The pipeline copies a block only when its index changes,
+  so a skipped block costs one grid step and no DMA.  A live block wholly
+  inside the band runs with no mask at all; only blocks that straddle the
+  diagonal or the window edge build the iota mask, which sets the scores
+  it drops to -inf (their p is then exactly 0).
+
+VMEM (v5e: 16 MiB scoped) at 512 x 1024 bf16 tiles, hd 128: the q, k, v
+and output tiles, double-buffered, 1.5 MiB; m, l (each padded to 128 lanes)
+and the accumulator 0.75 MiB; the float32 score and probability tiles and p
+in bf16 5 MiB.  About 7 MiB in all; float32 operands add 1.5 MiB of tiles.
 """
 from __future__ import annotations
 
@@ -27,15 +49,45 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
+_Q_TILES = (512, 256, 128)
+_K_TILES = (1024, 512, 256, 128)
 _NEG = -1e30
+
+
+def default_blocks(lq: int, lk: int) -> tuple[int, int]:
+    """(block_q, block_k) for sequence lengths ``lq`` and ``lk``: per axis,
+    the largest of its tiles that divides the length, else the length."""
+
+    def tile(n: int, tiles: tuple) -> int:
+        return next((t for t in tiles if n % t == 0), n)
+
+    return tile(lq, _Q_TILES), tile(lk, _K_TILES)
+
+
+def _band(qi, *, block_q: int, block_k: int, n_kv_blocks: int,
+          causal: bool, window: Optional[int]):
+    """First and last kv block that q block ``qi`` attends to."""
+    q_start = qi * block_q
+    first, last = 0, n_kv_blocks - 1
+    if causal:
+        last = jnp.minimum(last, (q_start + block_q - 1) // block_k)
+    if window is not None:
+        first = jnp.maximum(q_start + 1 - window, 0) // block_k
+    return first, last
+
+
+def _kv_block(qi, ki, **band_kw):
+    """The K/V block grid cell (qi, ki) reads: ki inside the band, else the
+    nearest live block, which the pipeline already holds, so nothing is
+    copied for a masked cell."""
+    first, last = _band(qi, **band_kw)
+    return jnp.minimum(jnp.maximum(ki, first), last)
 
 
 def _flash_kernel(
     q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
     *, scale: float, causal: bool, window: Optional[int],
-    block_q: int, block_k: int, n_kv_blocks: int,
+    block_q: int, block_k: int, n_kv_blocks: int, band,
 ):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
@@ -49,40 +101,52 @@ def _flash_kernel(
     q_start = qi * block_q
     k_start = ki * block_k
 
-    # skip kv blocks that are entirely masked out
-    live = True
-    if causal:
-        live = k_start <= q_start + block_q - 1
-    if window is not None:
-        live = jnp.logical_and(live, q_start - (k_start + block_k - 1) < window)
-
-    @pl.when(live)
-    def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # (block_q, hd)
-        k = k_ref[0, 0].astype(jnp.float32)  # (block_k, hd)
-        v = v_ref[0, 0].astype(jnp.float32)
+    def step(masked: bool):
         s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+            q_ref[0, 0], k_ref[0, 0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
         ) * scale  # (block_q, block_k)
+        if masked:
+            q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            keep = None
+            if causal:
+                keep = q_pos >= k_pos
+            if window is not None:
+                near = (q_pos - k_pos) < window
+                keep = near if keep is None else keep & near
+            s = jnp.where(keep, s, -jnp.inf)
 
-        q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0)
-        k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1)
-        mask = jnp.ones((block_q, block_k), jnp.bool_)
-        if causal:
-            mask &= q_pos >= k_pos
-        if window is not None:
-            mask &= (q_pos - k_pos) < window
-        s = jnp.where(mask, s, _NEG)
-
-        m_prev, l_prev, acc_prev = m_scr[...], l_scr[...], acc_scr[...]
+        m_prev = m_scr[...]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new) * mask.astype(jnp.float32)
+        p = jnp.exp(s - m_new)
         corr = jnp.exp(m_prev - m_new)
-        l_new = l_prev * corr + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = acc_prev * corr + jax.lax.dot(
+        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+        v = v_ref[0, 0]
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot(
             p.astype(v.dtype), v, preferred_element_type=jnp.float32
         )
-        m_scr[...], l_scr[...], acc_scr[...] = m_new, l_new, acc_new
+        m_scr[...] = m_new
+
+    if not causal and window is None:
+        step(masked=False)
+    else:
+        first, last = band(qi)
+        live = jnp.logical_and(ki >= first, ki <= last)
+        # inside the band: no (query, key) pair of the block is masked
+        inside = True
+        if causal:
+            inside = k_start + block_k - 1 <= q_start
+        if window is not None:
+            inside = jnp.logical_and(inside, q_start + block_q - 1 - k_start < window)
+
+        @pl.when(jnp.logical_and(live, inside))
+        def _inside():
+            step(masked=False)
+
+        @pl.when(jnp.logical_and(live, jnp.logical_not(inside)))
+        def _edge():
+            step(masked=True)
 
     @pl.when(ki == n_kv_blocks - 1)
     def _finalize():
@@ -98,37 +162,40 @@ def flash_attention(
     *,
     causal: bool = True,
     window: Optional[int] = None,
-    block_q: int = DEFAULT_BLOCK_Q,
-    block_k: int = DEFAULT_BLOCK_K,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     interpret: bool = False,
 ) -> jax.Array:
     b, h, lq, hd = q.shape
     n_kv, lk = k.shape[1], k.shape[2]
     assert h % n_kv == 0, (h, n_kv)
-    block_q = min(block_q, lq)
-    block_k = min(block_k, lk)
+    rule_q, rule_k = default_blocks(lq, lk)
+    block_q = min(block_q or rule_q, lq)
+    block_k = min(block_k or rule_k, lk)
     assert lq % block_q == 0 and lk % block_k == 0, (lq, block_q, lk, block_k)
     nq, nk = lq // block_q, lk // block_k
     scale = 1.0 / (hd**0.5)
+    band_kw = dict(
+        block_q=block_q, block_k=block_k, n_kv_blocks=nk, causal=causal, window=window,
+    )
+    band = functools.partial(_band, **band_kw)
+    kv_block = functools.partial(_kv_block, **band_kw)
+
+    def kv_index(b_, h_, qi, ki):
+        return (b_, h_ * n_kv // h, kv_block(qi, ki), 0)
 
     kernel = functools.partial(
         _flash_kernel,
         scale=scale, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, n_kv_blocks=nk,
+        block_q=block_q, block_k=block_k, n_kv_blocks=nk, band=band,
     )
     return pl.pallas_call(
         kernel,
         grid=(b, h, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, hd), lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
-            pl.BlockSpec(
-                (1, 1, block_k, hd),
-                lambda b_, h_, qi, ki, n_kv=n_kv, h=h: (b_, h_ * n_kv // h, ki, 0),
-            ),
-            pl.BlockSpec(
-                (1, 1, block_k, hd),
-                lambda b_, h_, qi, ki, n_kv=n_kv, h=h: (b_, h_ * n_kv // h, ki, 0),
-            ),
+            pl.BlockSpec((1, 1, block_k, hd), kv_index),
+            pl.BlockSpec((1, 1, block_k, hd), kv_index),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, hd), lambda b_, h_, qi, ki: (b_, h_, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b, h, lq, hd), q.dtype),

@@ -60,9 +60,10 @@ def flash_attention(
         "B": b, "H": h, "KV": k.shape[1], "L": lq, "hd": hd,
         "causal": causal, "window": window,
     }
+    rule_q, rule_k = _fa.default_blocks(lq, k.shape[2])
     cfg = _resolve(
         "flash_attention", shape, q.dtype,
-        {"block_q": _fa.DEFAULT_BLOCK_Q, "block_k": _fa.DEFAULT_BLOCK_K},
+        {"block_q": rule_q, "block_k": rule_k},
         {"block_q": block_q, "block_k": block_k},
     )
     return _flash_attention_jit(

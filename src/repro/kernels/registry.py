@@ -11,12 +11,13 @@ kernel as brokered work rather than a hand-called function:
   cost          the roofline cost model for one (shape, config) point:
                 FLOPs, modeled HBM traffic, VMEM tile footprint, grid cells
 
-The cost model mirrors the BlockSpec tiling exactly: traffic counts one tile
-fetch per *launched* grid cell (Pallas copies blocks for masked-out cells
-too), while FLOPs count only *live* cells (``pl.when`` skips the math), so
-larger attention blocks trade extra masked FLOPs for fewer cell launches and
-less re-fetched K/V — the three-way frontier the autotuner prunes on
-(kernels/autotune.py).
+The cost model mirrors the BlockSpec tiling: the pipeline copies a block
+only when its index changes.  Attention's K/V index map clamps masked-out
+cells onto a live block, so K/V traffic counts one tile fetch per *live*
+cell, q and output tiles once per q row; FLOPs count live cells only
+(``pl.when`` skips the math).  Larger attention blocks trade extra masked
+FLOPs for fewer cell launches and less re-fetched K/V — the three-way
+frontier the autotuner prunes on (kernels/autotune.py).
 
 Consumers: the autotuner, the ``kind="kernel"`` task runtime
 (core/managers/compute.py), benchmarks/kernels_bench.py, and the parity
@@ -128,7 +129,8 @@ def _fa_live_cells(shape: dict, config: dict) -> int:
 
 
 def _fa_defaults(shape: dict) -> dict:
-    return {"block_q": _fa.DEFAULT_BLOCK_Q, "block_k": _fa.DEFAULT_BLOCK_K}
+    block_q, block_k = _fa.default_blocks(shape["L"], shape["L"])
+    return {"block_q": block_q, "block_k": block_k}
 
 
 def _fa_make_args(shape: dict, dtype: str, seed: int) -> tuple:
@@ -170,9 +172,9 @@ def _fa_cost(shape: dict, config: dict, dtype: str) -> Cost:
     cells = B * H * nq * nk
     # two MXU matmuls (q@k^T and p@v) per LIVE cell; masked cells skip math
     flops = 4.0 * B * H * live * bq * bk * hd
-    # tile traffic per LAUNCHED cell (block copies happen even when masked):
-    # q tile + k tile + v tile in, plus the output written once per q row
-    hbm = isz * B * H * (nq * nk * (bq + 2 * bk) * hd + shape["L"] * hd)
+    # a k tile and a v tile per LIVE cell (masked cells repeat a live block's
+    # index, so nothing is copied for them); q read and output written once
+    hbm = isz * B * H * (live * 2 * bk * hd + 2 * shape["L"] * hd)
     # q/k/v input tiles and the output tile, each double-buffered by the
     # pipeline, + fp32 scratch (m and l are (bq, 1): a full lane row each)
     vmem = 2 * isz * (bq + 2 * bk + bq) * hd + 4 * bq * (2 * 128 + hd)

@@ -202,3 +202,16 @@ def test_unset_autotuner_only_clears_its_own_installation():
         os.environ.pop("HYDRA_AUTOTUNE", None)
         unset_autotuner(a)
     assert tuned_config(name, shape) is None
+
+
+@pytest.mark.parametrize("causal,window,live", [(False, None, 16), (True, None, 10), (True, 64, 7)])
+def test_attention_cost_fetches_kv_for_live_cells_only(causal, window, live):
+    """The K/V index map clamps masked cells onto a live block, so the model
+    counts a k and a v tile per live cell, q and the output once each."""
+    kdef = kreg.get_kernel("flash_attention")
+    B, H, L, hd, blk, isz = 1, 2, 256, 64, 64, 4
+    shape = {"B": B, "H": H, "KV": 1, "L": L, "hd": hd, "causal": causal, "window": window}
+    cost = kdef.cost(shape, {"block_q": blk, "block_k": blk}, "float32")
+    assert cost.grid_cells == B * H * 4 * 4  # every cell is still a grid step
+    assert cost.flops == 4.0 * B * H * live * blk * blk * hd
+    assert cost.hbm_bytes == isz * B * H * (live * 2 * blk * hd + 2 * L * hd)

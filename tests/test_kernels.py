@@ -56,6 +56,102 @@ def test_flash_attention_non_causal():
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
+# the tiles ops.flash_attention takes from the one rule when no block is
+# given: 384 -> 128 x 128 (no larger tile divides it), 2048 -> 512 x 1024
+_MASKS = {
+    "causal": {"causal": True, "window": None},
+    "windowed": {"causal": True, "window": 100},
+    "full": {"causal": False, "window": None},
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("mask", sorted(_MASKS))
+@pytest.mark.parametrize("L", [384, 2048])
+def test_flash_attention_default_tiles(dtype, mask, L):
+    """Parity at the shape-derived default tiles, with a 6:1 GQA group."""
+    B, H, KV, hd = 1, 12, 2, 128
+    q = jnp.asarray(RNG.normal(size=(B, H, L, hd)), dtype)
+    k = jnp.asarray(RNG.normal(size=(B, KV, L, hd)), dtype)
+    v = jnp.asarray(RNG.normal(size=(B, KV, L, hd)), dtype)
+    got = ops.flash_attention(q, k, v, **_MASKS[mask])
+    want = ref.attention_ref(q, k, v, **_MASKS[mask])
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32), **_tol(dtype)
+    )
+
+
+def test_flash_attention_one_tile_rule(monkeypatch):
+    """ops.flash_attention and the registry's defaults resolve the same
+    tiles, both from flash_attention.default_blocks."""
+    from repro.kernels import flash_attention as fa
+    from repro.kernels import registry as kreg
+
+    monkeypatch.delenv("HYDRA_AUTOTUNE", raising=False)
+    seen = {}
+    monkeypatch.setattr(ops, "_flash_attention_jit", lambda q, k, v, **kw: seen.update(kw))
+    want = {
+        64: (64, 64), 128: (128, 128), 192: (192, 192), 384: (128, 128),
+        768: (256, 256), 1024: (512, 1024), 1536: (512, 512), 8192: (512, 1024),
+    }
+    for L, (bq, bk) in want.items():
+        q = jax.ShapeDtypeStruct((1, 4, L, 128), jnp.bfloat16)
+        kv = jax.ShapeDtypeStruct((1, 2, L, 128), jnp.bfloat16)
+        ops.flash_attention(q, kv, kv)
+        shape = {"B": 1, "H": 4, "KV": 2, "L": L, "hd": 128, "causal": True, "window": None}
+        registry = kreg.get_kernel("flash_attention").defaults(shape)
+        assert (seen["block_q"], seen["block_k"]) == fa.default_blocks(L, L) == (bq, bk)
+        assert registry == {"block_q": bq, "block_k": bk}
+
+
+@pytest.mark.parametrize("causal,window", [(True, None), (True, 100), (False, None), (False, 100)])
+def test_flash_attention_masked_cells_copy_nothing(causal, window):
+    """Walking the grid in order, the K/V index map names only live blocks,
+    a live cell its own block, and a masked cell the block before it: the
+    pipeline copies no more K/V tiles than there are live cells."""
+    from repro.kernels import flash_attention as fa
+    from repro.kernels import registry as kreg
+
+    L, bq, bk = 1024, 128, 256
+    band = dict(block_q=bq, block_k=bk, n_kv_blocks=L // bk, causal=causal, window=window)
+    copies, prev = 0, None
+    for qi in range(L // bq):
+        first, last = (int(x) for x in fa._band(qi, **band))
+        for ki in range(L // bk):
+            blk = int(fa._kv_block(qi, ki, **band))
+            assert first <= blk <= last
+            assert blk == ki or not first <= ki <= last
+            copies += blk != prev
+            prev = blk
+    shape = {"L": L, "causal": causal, "window": window}
+    assert copies <= kreg._fa_live_cells(shape, {"block_q": bq, "block_k": bk})
+
+
+def _kernel_dots(dtype) -> list:
+    """Operand dtypes of each matmul inside the attention kernel's body."""
+    from repro.kernels import flash_attention as fa
+
+    q = jax.ShapeDtypeStruct((1, 2, 256, 128), dtype)
+    kv = jax.ShapeDtypeStruct((1, 1, 256, 128), dtype)
+    jaxpr = jax.make_jaxpr(lambda q, k, v: fa.flash_attention(q, k, v))(q, kv, kv)
+    (call,) = [e for e in jaxpr.eqns if e.primitive.name == "pallas_call"]
+    dots, todo = [], [call.params["jaxpr"]]
+    while todo:
+        for e in todo.pop().eqns:
+            if e.primitive.name == "dot_general":
+                dots.append((tuple(str(x.aval.dtype) for x in e.invars), str(e.outvars[0].aval.dtype)))
+            todo += [j.jaxpr if hasattr(j, "jaxpr") else j for j in jax.core.jaxprs_in_params(e.params)]
+    return dots
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_flash_attention_matmuls_take_the_payload_dtype(dtype):
+    """Both matmuls take the operands' own dtype and accumulate in float32:
+    bf16 on the MXU's native path, float32 payloads stay float32."""
+    dots = _kernel_dots(jnp.dtype(dtype))
+    assert dots and all(ins == (dtype, dtype) and out == "float32" for ins, out in dots), dots
+
+
 @pytest.mark.parametrize("B,ck,di,N,block_d", [(1, 16, 64, 4, 32), (2, 32, 128, 16, 64), (2, 64, 256, 16, 256)])
 def test_selective_scan_sweep(B, ck, di, N, block_d):
     x = jnp.asarray(RNG.normal(size=(B, ck, di)), jnp.float32)
