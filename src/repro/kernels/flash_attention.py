@@ -34,6 +34,12 @@ Masked blocks
   diagonal or the window edge build the iota mask, which sets the scores
   it drops to -inf (their p is then exactly 0).
 
+Shared pieces
+  ``band``, ``kv_block``, ``banded`` (the masked-block skip),
+  ``mask_scores``, ``init_state``, ``softmax_update`` and ``normalized``
+  are the kernel's steps as functions; mla_attention.py builds its two
+  kernels from them, with queries offset from the keys (``q_offset``).
+
 VMEM (v5e: 16 MiB scoped) at 512 x 1024 bf16 tiles, hd 128: the q, k, v
 and output tiles, double-buffered, 1.5 MiB; m, l (each padded to 128 lanes)
 and the accumulator 0.75 MiB; the float32 score and probability tiles and p
@@ -64,10 +70,14 @@ def default_blocks(lq: int, lk: int) -> tuple[int, int]:
     return tile(lq, _Q_TILES), tile(lk, _K_TILES)
 
 
-def _band(qi, *, block_q: int, block_k: int, n_kv_blocks: int,
-          causal: bool, window: Optional[int]):
-    """First and last kv block that q block ``qi`` attends to."""
+def band(qi, *, block_q: int, block_k: int, n_kv_blocks: int,
+         causal: bool, window: Optional[int], q_offset: int = 0):
+    """First and last kv block that q block ``qi`` attends to.  The queries
+    sit at key positions ``q_offset + qi * block_q ...``: 0 when queries
+    and keys are one sequence, ``Lk - Lq`` when the queries are its end."""
     q_start = qi * block_q
+    if q_offset:
+        q_start = q_start + q_offset
     first, last = 0, n_kv_blocks - 1
     if causal:
         last = jnp.minimum(last, (q_start + block_q - 1) // block_k)
@@ -76,21 +86,16 @@ def _band(qi, *, block_q: int, block_k: int, n_kv_blocks: int,
     return first, last
 
 
-def _kv_block(qi, ki, **band_kw):
+def kv_block(qi, ki, **band_kw):
     """The K/V block grid cell (qi, ki) reads: ki inside the band, else the
     nearest live block, which the pipeline already holds, so nothing is
     copied for a masked cell."""
-    first, last = _band(qi, **band_kw)
+    first, last = band(qi, **band_kw)
     return jnp.minimum(jnp.maximum(ki, first), last)
 
 
-def _flash_kernel(
-    q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
-    *, scale: float, causal: bool, window: Optional[int],
-    block_q: int, block_k: int, n_kv_blocks: int, band,
-):
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
+def init_state(ki, m_scr, l_scr, acc_scr) -> None:
+    """Empties the online softmax's state at the first kv block."""
 
     @pl.when(ki == 0)
     def _init():
@@ -98,61 +103,100 @@ def _flash_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
+
+def mask_scores(s, q_start, k_start, *, causal: bool, window: Optional[int]):
+    """``s`` with the pairs outside the causal / window band set to -inf
+    (their p is then exactly 0); ``q_start`` and ``k_start`` are the key
+    positions of its first row and column."""
+    q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    keep = None
+    if causal:
+        keep = q_pos >= k_pos
+    if window is not None:
+        near = (q_pos - k_pos) < window
+        keep = near if keep is None else keep & near
+    return jnp.where(keep, s, -jnp.inf)
+
+
+def softmax_update(s, v_ref, m_scr, l_scr, acc_scr) -> None:
+    """One online-softmax step: float32 scores ``s`` (rows, block_k) against
+    the values ``v_ref`` holds (block_k, dv); p is cast to their dtype for
+    the MXU and accumulates in float32."""
+    m_prev = m_scr[...]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    p = jnp.exp(s - m_new)
+    corr = jnp.exp(m_prev - m_new)
+    l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
+    v = v_ref[...]
+    acc_scr[...] = acc_scr[...] * corr + jax.lax.dot(
+        p.astype(v.dtype), v, preferred_element_type=jnp.float32
+    )
+    m_scr[...] = m_new
+
+
+def normalized(l_scr, acc_scr):
+    """The attention output the state holds after the last kv block."""
+    return acc_scr[...] / jnp.maximum(l_scr[...], 1e-37)
+
+
+def dot_nt(a, b):
+    """a (m, d) . b (n, d)^T in float32, operands in their own dtype."""
+    return jax.lax.dot_general(
+        a, b, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    )
+
+
+def banded(step, qi, ki, q_start, k_start, *, block_q: int, block_k: int,
+           causal: bool, window: Optional[int], band_fn) -> None:
+    """Runs ``step(masked)`` for grid cell (qi, ki): not at all outside the
+    band (``band_fn``), unmasked for a block wholly inside it, masked for a
+    block on its edge.  ``q_start``, ``k_start``: the block's first key
+    positions."""
+    if not causal and window is None:
+        step(masked=False)
+        return
+    first, last = band_fn(qi)
+    live = jnp.logical_and(ki >= first, ki <= last)
+    # inside the band: no (query, key) pair of the block is masked
+    inside = True
+    if causal:
+        inside = k_start + block_k - 1 <= q_start
+    if window is not None:
+        inside = jnp.logical_and(inside, q_start + block_q - 1 - k_start < window)
+
+    @pl.when(jnp.logical_and(live, inside))
+    def _inside():
+        step(masked=False)
+
+    @pl.when(jnp.logical_and(live, jnp.logical_not(inside)))
+    def _edge():
+        step(masked=True)
+
+
+def _flash_kernel(
+    q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
+    *, scale: float, causal: bool, window: Optional[int],
+    block_q: int, block_k: int, n_kv_blocks: int, band_fn,
+):
+    qi = pl.program_id(2)
+    ki = pl.program_id(3)
+    init_state(ki, m_scr, l_scr, acc_scr)
     q_start = qi * block_q
     k_start = ki * block_k
 
     def step(masked: bool):
-        s = jax.lax.dot_general(
-            q_ref[0, 0], k_ref[0, 0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * scale  # (block_q, block_k)
+        s = dot_nt(q_ref[0, 0], k_ref[0, 0]) * scale  # (block_q, block_k)
         if masked:
-            q_pos = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            k_pos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            keep = None
-            if causal:
-                keep = q_pos >= k_pos
-            if window is not None:
-                near = (q_pos - k_pos) < window
-                keep = near if keep is None else keep & near
-            s = jnp.where(keep, s, -jnp.inf)
+            s = mask_scores(s, q_start, k_start, causal=causal, window=window)
+        softmax_update(s, v_ref.at[0, 0], m_scr, l_scr, acc_scr)
 
-        m_prev = m_scr[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_scr[...] = l_scr[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
-        v = v_ref[0, 0]
-        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot(
-            p.astype(v.dtype), v, preferred_element_type=jnp.float32
-        )
-        m_scr[...] = m_new
-
-    if not causal and window is None:
-        step(masked=False)
-    else:
-        first, last = band(qi)
-        live = jnp.logical_and(ki >= first, ki <= last)
-        # inside the band: no (query, key) pair of the block is masked
-        inside = True
-        if causal:
-            inside = k_start + block_k - 1 <= q_start
-        if window is not None:
-            inside = jnp.logical_and(inside, q_start + block_q - 1 - k_start < window)
-
-        @pl.when(jnp.logical_and(live, inside))
-        def _inside():
-            step(masked=False)
-
-        @pl.when(jnp.logical_and(live, jnp.logical_not(inside)))
-        def _edge():
-            step(masked=True)
+    banded(step, qi, ki, q_start, k_start, block_q=block_q, block_k=block_k,
+           causal=causal, window=window, band_fn=band_fn)
 
     @pl.when(ki == n_kv_blocks - 1)
     def _finalize():
-        o_ref[0, 0] = (
-            acc_scr[...] / jnp.maximum(l_scr[...], 1e-37)
-        ).astype(o_ref.dtype)
+        o_ref[0, 0] = normalized(l_scr, acc_scr).astype(o_ref.dtype)
 
 
 def flash_attention(
@@ -178,16 +222,16 @@ def flash_attention(
     band_kw = dict(
         block_q=block_q, block_k=block_k, n_kv_blocks=nk, causal=causal, window=window,
     )
-    band = functools.partial(_band, **band_kw)
-    kv_block = functools.partial(_kv_block, **band_kw)
+    band_fn = functools.partial(band, **band_kw)
+    kv_of = functools.partial(kv_block, **band_kw)
 
     def kv_index(b_, h_, qi, ki):
-        return (b_, h_ * n_kv // h, kv_block(qi, ki), 0)
+        return (b_, h_ * n_kv // h, kv_of(qi, ki), 0)
 
     kernel = functools.partial(
         _flash_kernel,
         scale=scale, causal=causal, window=window,
-        block_q=block_q, block_k=block_k, n_kv_blocks=nk, band=band,
+        block_q=block_q, block_k=block_k, n_kv_blocks=nk, band_fn=band_fn,
     )
     return pl.pallas_call(
         kernel,

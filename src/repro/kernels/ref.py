@@ -70,3 +70,30 @@ def moe_gmm_ref(x, w):
     return jnp.einsum(
         "ecd,edf->ecf", x.astype(jnp.float32), w.astype(jnp.float32)
     ).astype(x.dtype)
+
+
+def mla_ref(q_nope, q_rope, c_kv, k_rope, w_uk, w_uv, *, scale: float):
+    """Standard (non-absorbed) multi-head latent attention in float32 at the
+    highest matmul precision, one head at a time.
+
+    q_nope (B, H, Lq, dn), q_rope (B, H, Lq, dr); c_kv (B, Lk, dc), k_rope
+    (B, Lk, dr); w_uk (dc, H, dn), w_uv (dc, H, dv).  Head h's keys
+    ``[c_kv W_UK_h, k_rope]`` and values ``c_kv W_UV_h`` are decompressed,
+    then exact softmax attention with ``scale``, causal, the queries at key
+    positions ``Lk - Lq ... Lk - 1`` -> (B, H, Lq, dv) in q's dtype."""
+    f32 = lambda a: a.astype(jnp.float32)  # noqa: E731
+    lq, lk = q_nope.shape[2], c_kv.shape[1]
+    c, k_rope = f32(c_kv), f32(k_rope)
+    mask = (lk - lq + jnp.arange(lq))[:, None] >= jnp.arange(lk)[None, :]
+
+    def head(operands):
+        qn, qr, wk, wv = (f32(a) for a in operands)  # (B, Lq, dn), (B, Lq, dr), (dc, dn), (dc, dv)
+        k = jnp.concatenate([c @ wk, k_rope], axis=-1)  # (B, Lk, dn + dr)
+        s = jnp.einsum("bqd,bkd->bqk", jnp.concatenate([qn, qr], axis=-1), k) * scale
+        p = jax.nn.softmax(jnp.where(mask[None], s, -1e30), axis=-1)
+        return jnp.einsum("bqk,bkd->bqd", p, c @ wv)
+
+    with jax.default_matmul_precision("highest"):
+        out = jax.lax.map(head, (jnp.moveaxis(q_nope, 1, 0), jnp.moveaxis(q_rope, 1, 0),
+                                 jnp.moveaxis(w_uk, 1, 0), jnp.moveaxis(w_uv, 1, 0)))
+    return jnp.moveaxis(out, 0, 1).astype(q_nope.dtype)

@@ -34,6 +34,7 @@ import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels import flash_attention as _fa
+from repro.kernels import mla_attention as _mla
 from repro.kernels import moe_gmm as _gmm
 from repro.kernels import ref as _ref
 from repro.kernels import rglru_scan as _rg
@@ -113,19 +114,25 @@ def _fa_blocks(shape: dict, config: dict) -> tuple:
     return bq, bk, lq // bq, lq // bk
 
 
-def _fa_live_cells(shape: dict, config: dict) -> int:
-    bq, bk, nq, nk = _fa_blocks(shape, config)
-    window = shape.get("window")
+def _live_cells(nq: int, nk: int, bq: int, bk: int, causal: bool, window, q_offset: int = 0) -> int:
+    """Grid cells (qi, ki) inside the causal / window band, the queries at
+    key positions ``q_offset + qi * bq ...``."""
     live = 0
     for qi in range(nq):
+        q0 = q_offset + qi * bq
         for ki in range(nk):
             ok = True
-            if shape.get("causal", True):
-                ok = ki * bk <= qi * bq + bq - 1
+            if causal:
+                ok = ki * bk <= q0 + bq - 1
             if window is not None:
-                ok = ok and (qi * bq - (ki * bk + bk - 1) < window)
+                ok = ok and (q0 - (ki * bk + bk - 1) < window)
             live += ok
     return live
+
+
+def _fa_live_cells(shape: dict, config: dict) -> int:
+    bq, bk, nq, nk = _fa_blocks(shape, config)
+    return _live_cells(nq, nk, bq, bk, shape.get("causal", True), shape.get("window"))
 
 
 def _fa_defaults(shape: dict) -> dict:
@@ -179,6 +186,119 @@ def _fa_cost(shape: dict, config: dict, dtype: str) -> Cost:
     # pipeline, + fp32 scratch (m and l are (bq, 1): a full lane row each)
     vmem = 2 * isz * (bq + 2 * bk + bq) * hd + 4 * bq * (2 * 128 + hd)
     return Cost(flops, float(hbm), float(vmem), cells)
+
+
+# ---------------------------------------------------------------------------
+# mla_prefill / mla_decode (DeepSeek-V3 multi-head latent attention)
+# ---------------------------------------------------------------------------
+
+
+def _mla_make_args(shape: dict, dtype: str, seed: int) -> tuple:
+    """q_nope, q_rope, c_kv, k_rope, w_uk, w_uv, after RoPE.  A decode
+    shape (no ``Lq``) has one query per head and a (B, Lk, dr) rope cache;
+    a prefill shape keeps k_rope as one head every query head reads."""
+    kqn, kqr, kc, kr, ku, kv = jax.random.split(jax.random.PRNGKey(seed), 6)
+    B, H, Lk, dc, dn, dr, dv = (shape[k] for k in ("B", "H", "Lk", "dc", "dn", "dr", "dv"))
+    dt = jnp.dtype(dtype)
+    decode = "Lq" not in shape
+    q = (B, H) if decode else (B, H, shape["Lq"])
+    rope = (B, Lk, dr) if decode else (B, 1, Lk, dr)
+
+    def weight(key, d):
+        return (jax.random.normal(key, (dc, H, d), jnp.float32) * (1.0 / dc**0.5)).astype(dt)
+
+    return (
+        jax.random.normal(kqn, q + (dn,), dt), jax.random.normal(kqr, q + (dr,), dt),
+        jax.random.normal(kc, (B, Lk, dc), dt), jax.random.normal(kr, rope, dt),
+        weight(ku, dn), weight(kv, dv),
+    )
+
+
+def _mlap_blocks(shape: dict, config: dict) -> tuple:
+    bq, bk = min(config["block_q"], shape["Lq"]), min(config["block_k"], shape["Lk"])
+    return bq, bk, shape["Lq"] // bq, shape["Lk"] // bk
+
+
+def _mlap_defaults(shape: dict) -> dict:
+    block_q, block_k = _fa.default_blocks(shape["Lq"], shape["Lk"])
+    return {"block_q": block_q, "block_k": block_k}
+
+
+def _mlap_call(shape: dict, args: tuple, config: dict, interpret: bool):
+    return _mla.prefill_program(
+        *args, scale=shape["scale"], block_q=config["block_q"], block_k=config["block_k"],
+        interpret=interpret,
+    )
+
+
+def _mlap_ref(shape: dict, args: tuple):
+    q_nope, q_rope, c_kv, k_rope, w_uk, w_uv = args
+    return _ref.mla_ref(q_nope, q_rope, c_kv, k_rope[:, 0], w_uk, w_uv, scale=shape["scale"])
+
+
+def _mlap_space(shape: dict) -> list:
+    return [
+        {"block_q": bq, "block_k": bk}
+        for bq in _divisors(shape["Lq"], candidates=(32, 64, 128, 256, 512))
+        for bk in _divisors(shape["Lk"])
+    ]
+
+
+def _mla_decompress(shape: dict, isz: int) -> tuple:
+    """FLOPs and HBM bytes of the XLA projections around an MLA kernel:
+    prefill decompresses the latent into K and V per head; decode absorbs
+    the query and un-absorbs the output."""
+    B, H, Lk, dc, dn, dv = (shape[k] for k in ("B", "H", "Lk", "dc", "dn", "dv"))
+    w = isz * dc * H * (dn + dv)
+    if "Lq" in shape:
+        return 2.0 * B * Lk * dc * H * (dn + dv), isz * (B * Lk * dc + B * H * Lk * (dn + dv)) + w
+    return 2.0 * B * H * dc * (dn + dv), isz * B * H * (dn + 2 * dc + dv) + w
+
+
+def _mlap_cost(shape: dict, config: dict, dtype: str) -> Cost:
+    B, H, Lq, Lk, dn, dr, dv = (shape[k] for k in ("B", "H", "Lq", "Lk", "dn", "dr", "dv"))
+    isz = _isz(dtype)
+    bq, bk, nq, nk = _mlap_blocks(shape, config)
+    live = _live_cells(nq, nk, bq, bk, True, None, q_offset=Lk - Lq)
+    xla_flops, xla_bytes = _mla_decompress(shape, isz)
+    # per LIVE cell: the two score parts and p.v; masked cells skip math
+    flops = 2.0 * B * H * live * bq * bk * (dn + dr + dv) + xla_flops
+    # k_nope, k_rope and v tiles per LIVE cell; q tiles and output once a row
+    hbm = isz * B * H * (live * bk * (dn + dr + dv) + Lq * (dn + dr + dv)) + xla_bytes
+    # the six tiles double-buffered (rope tiles pay whole lanes) + m, l, acc
+    vmem = 2 * isz * (bq * (dn + _lanes(dr) + dv) + bk * (dn + _lanes(dr) + dv)) + 4 * bq * (2 * 128 + dv)
+    return Cost(flops, float(hbm), float(vmem), B * H * nq * nk)
+
+
+def _mlad_defaults(shape: dict) -> dict:
+    return {"block_k": _fa.default_blocks(shape["H"], shape["Lk"])[1]}
+
+
+def _mlad_call(shape: dict, args: tuple, config: dict, interpret: bool):
+    return _mla.decode_program(*args, scale=shape["scale"], block_k=config["block_k"], interpret=interpret)
+
+
+def _mlad_ref(shape: dict, args: tuple):
+    q_nope, q_rope, c_kv, k_rope, w_uk, w_uv = args
+    out = _ref.mla_ref(q_nope[:, :, None], q_rope[:, :, None], c_kv, k_rope, w_uk, w_uv, scale=shape["scale"])
+    return out[:, :, 0]
+
+
+def _mlad_space(shape: dict) -> list:
+    return [{"block_k": bk} for bk in _divisors(shape["Lk"])]
+
+
+def _mlad_cost(shape: dict, config: dict, dtype: str) -> Cost:
+    B, H, Lk, dc, dr = (shape[k] for k in ("B", "H", "Lk", "dc", "dr"))
+    isz = _isz(dtype)
+    bk = min(config["block_k"], Lk)
+    xla_flops, xla_bytes = _mla_decompress(shape, isz)
+    # scores against [c_kv, k_rope], then p.c_kv, for every head and key
+    flops = 2.0 * B * H * Lk * (dc + dr + dc) + xla_flops
+    # the latent and rope caches read once; queries in and output out once
+    hbm = isz * (B * Lk * (dc + dr) + B * H * (2 * dc + dr)) + xla_bytes
+    vmem = 2 * isz * (H * (2 * dc + _lanes(dr)) + bk * (dc + _lanes(dr))) + 4 * H * (2 * 128 + dc)
+    return Cost(flops, float(hbm), float(vmem), B * (Lk // bk))
 
 
 # ---------------------------------------------------------------------------
@@ -369,6 +489,32 @@ KERNELS: dict = {
             full_shape={"B": 1, "H": 8, "KV": 2, "L": 512, "hd": 64, "causal": True, "window": None},
         ),
         KernelDef(
+            name="mla_prefill",
+            params=("block_q", "block_k"),
+            defaults=_mlap_defaults,
+            make_args=_mla_make_args,
+            call=_mlap_call,
+            ref=_mlap_ref,
+            space=_mlap_space,
+            cost=_mlap_cost,
+            tiny_shape={"B": 1, "H": 2, "Lq": 32, "Lk": 64, "dc": 64, "dn": 32, "dr": 16, "dv": 32, "scale": 0.2},
+            smoke_shape={"B": 1, "H": 4, "Lq": 64, "Lk": 256, "dc": 128, "dn": 64, "dr": 32, "dv": 64, "scale": 0.125},
+            full_shape={"B": 1, "H": 8, "Lq": 128, "Lk": 512, "dc": 128, "dn": 128, "dr": 64, "dv": 128, "scale": 0.1},
+        ),
+        KernelDef(
+            name="mla_decode",
+            params=("block_k",),
+            defaults=_mlad_defaults,
+            make_args=_mla_make_args,
+            call=_mlad_call,
+            ref=_mlad_ref,
+            space=_mlad_space,
+            cost=_mlad_cost,
+            tiny_shape={"B": 2, "H": 8, "Lk": 64, "dc": 64, "dn": 32, "dr": 16, "dv": 32, "scale": 0.2},
+            smoke_shape={"B": 2, "H": 16, "Lk": 256, "dc": 128, "dn": 64, "dr": 32, "dv": 64, "scale": 0.125},
+            full_shape={"B": 4, "H": 16, "Lk": 512, "dc": 128, "dn": 128, "dr": 64, "dv": 128, "scale": 0.1},
+        ),
+        KernelDef(
             name="selective_scan",
             params=("block_d",),
             defaults=_ss_defaults,
@@ -435,13 +581,21 @@ def interpret_default(device=None) -> bool:
 def published_shapes() -> dict:
     """Each kernel at the widths of a published model that carries it:
     name -> (arch, shape, dtypes).  One chip's share of the model: batch 1,
-    one expert, one SSM chunk."""
+    one expert, one SSM chunk; for latent attention the last 2,048-token
+    chunk of a 16k prompt and one decode step of 32 such sequences (MLA
+    runs data-parallel: a chip holds every head of its sequences)."""
     from repro.configs import get_arch
+    from repro.configs.deepseek_v3 import CONFIG as dsv3
 
     llama = get_arch("llama3-8b")
     mamba = get_arch("falcon-mamba-7b")
     rgemma = get_arch("recurrentgemma-2b")
     grok = get_arch("grok-1-314b")
+    context = 16384
+    mla = {
+        "H": dsv3.n_heads, "Lk": context, "dc": dsv3.kv_lora_rank, "dn": dsv3.qk_nope_head_dim,
+        "dr": dsv3.qk_rope_head_dim, "dv": dsv3.v_head_dim, "scale": dsv3.softmax_scale(context),
+    }
     return {
         "flash_attention": (llama.name, {
             "B": 1, "H": llama.n_heads, "KV": llama.n_kv_heads, "L": 2048,
@@ -456,6 +610,8 @@ def published_shapes() -> dict:
         "moe_gmm": (grok.name, {
             "E": 1, "C": 1024, "D": grok.d_model, "F": grok.d_ff,
         }, ("bfloat16",)),
+        "mla_prefill": (dsv3.name, {"B": 1, "Lq": 2048, **mla}, ("bfloat16",)),
+        "mla_decode": (dsv3.name, {"B": 32, **mla}, ("bfloat16",)),
     }
 
 

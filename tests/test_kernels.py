@@ -116,9 +116,9 @@ def test_flash_attention_masked_cells_copy_nothing(causal, window):
     band = dict(block_q=bq, block_k=bk, n_kv_blocks=L // bk, causal=causal, window=window)
     copies, prev = 0, None
     for qi in range(L // bq):
-        first, last = (int(x) for x in fa._band(qi, **band))
+        first, last = (int(x) for x in fa.band(qi, **band))
         for ki in range(L // bk):
-            blk = int(fa._kv_block(qi, ki, **band))
+            blk = int(fa.kv_block(qi, ki, **band))
             assert first <= blk <= last
             assert blk == ki or not first <= ki <= last
             copies += blk != prev
