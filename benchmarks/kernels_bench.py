@@ -71,7 +71,7 @@ def kernel_rows(full: bool = False) -> list[tuple]:
         args = kdef.make_args(shape, "float32", 0)
         t_ref = _time_us(lambda: kdef.ref(shape, args))
         err = kreg.max_abs_err(
-            kdef.call(shape, args, kdef.defaults(shape), interpret),
+            kdef.call(shape, args, kdef.defaults(shape, "float32"), interpret),
             kdef.ref(shape, args),
         )
         rows.append(
@@ -91,16 +91,15 @@ def exp14_row(reps: int = 3) -> tuple:
         result = tuner.tune(name, shape, "float32")
         min_cut = min(min_cut, result.sweep_cut)
         args = kdef.make_args(shape, "float32", 0)
-        default_s = _min_s(
-            lambda: kdef.call(shape, args, kdef.defaults(shape), interpret), reps
-        )
+        default = kdef.defaults(shape, "float32")
+        default_s = _min_s(lambda: kdef.call(shape, args, default, interpret), reps)
         tuned_s = _min_s(
             lambda: kdef.call(shape, args, result.config, interpret), reps
         )
         speedup = default_s / tuned_s if tuned_s > 0 else float("inf")
         print(
             f"  exp14 {name}: tuned {kreg.config_sig(result.config)} "
-            f"{tuned_s:.4f}s vs default {kreg.config_sig(kdef.defaults(shape))} "
+            f"{tuned_s:.4f}s vs default {kreg.config_sig(default)} "
             f"{default_s:.4f}s -> {speedup:.2f}x (cut {result.sweep_cut:.1f})"
         )
         if best is None or speedup > best[0]:

@@ -115,7 +115,7 @@ def _program(p: dict, device):
     from repro.kernels import registry as kreg
 
     kdef = kreg.get_kernel(p["kernel"])
-    return kdef, kreg.compiled(kdef, p["shape"], p["dtype"], kdef.defaults(p["shape"]), device)
+    return kdef, kreg.compiled(kdef, p["shape"], p["dtype"], kdef.defaults(p["shape"], p["dtype"]), device)
 
 
 def phase_kernels(device) -> None:

@@ -172,7 +172,7 @@ class KernelRuntime:
         dtype = spec.get("dtype", "float32")
         reps = max(1, int(spec.get("reps", 1)))
         seed = int(spec.get("seed", 0))
-        config = spec.get("config") or tuned_config(kdef.name, shape, dtype) or kdef.defaults(shape)
+        config = spec.get("config") or tuned_config(kdef.name, shape, dtype) or kdef.defaults(shape, dtype)
         program = kreg.compiled(kdef, shape, dtype, config, device)
         with span("kernel.operands", uid=task.uid):
             args = kreg.operands(kdef, shape, dtype, seed, device)

@@ -162,7 +162,7 @@ class Autotuner:
         if not fits:
             # every candidate over budget (degenerate tiny-VMEM override):
             # fall back to the kernel defaults rather than an empty sweep
-            return [kdef.defaults(shape)], exhaustive
+            return [kdef.defaults(shape, dtype)], exhaustive
 
         def dominated(ci: kreg.Cost) -> bool:
             for _, cj in fits:

@@ -117,14 +117,12 @@ def moe_gmm(
 ):
     """Grouped expert matmul: x (E,C,D) @ w (E,D,F) -> (E,C,F)."""
     E, C, D = x.shape
-    shape = {"E": E, "C": C, "D": D, "F": w.shape[-1]}
+    F = w.shape[-1]
+    shape = {"E": E, "C": C, "D": D, "F": F}
+    rule_c, rule_f, rule_d = _gmm.default_blocks(C, D, F, x.dtype.itemsize)
     cfg = _resolve(
         "moe_gmm", shape, x.dtype,
-        {
-            "block_c": _gmm.DEFAULT_BLOCK_C,
-            "block_f": _gmm.DEFAULT_BLOCK_F,
-            "block_d": _gmm.DEFAULT_BLOCK_D,
-        },
+        {"block_c": rule_c, "block_f": rule_f, "block_d": rule_d},
         {"block_c": block_c, "block_f": block_f, "block_d": block_d},
     )
     return _moe_gmm_jit(

@@ -64,7 +64,7 @@ class Cost:
 class KernelDef:
     name: str
     params: tuple  # config keys, canonical order
-    defaults: Callable[[dict], dict]
+    defaults: Callable[[dict, str], dict]  # (shape, dtype) -> the tile rule's config
     make_args: Callable[[dict, str, int], tuple]
     call: Callable[[dict, tuple, dict, bool], Any]
     ref: Callable[[dict, tuple], Any]
@@ -135,7 +135,7 @@ def _fa_live_cells(shape: dict, config: dict) -> int:
     return _live_cells(nq, nk, bq, bk, shape.get("causal", True), shape.get("window"))
 
 
-def _fa_defaults(shape: dict) -> dict:
+def _fa_defaults(shape: dict, dtype: str) -> dict:
     block_q, block_k = _fa.default_blocks(shape["L"], shape["L"])
     return {"block_q": block_q, "block_k": block_k}
 
@@ -219,7 +219,7 @@ def _mlap_blocks(shape: dict, config: dict) -> tuple:
     return bq, bk, shape["Lq"] // bq, shape["Lk"] // bk
 
 
-def _mlap_defaults(shape: dict) -> dict:
+def _mlap_defaults(shape: dict, dtype: str) -> dict:
     block_q, block_k = _fa.default_blocks(shape["Lq"], shape["Lk"])
     return {"block_q": block_q, "block_k": block_k}
 
@@ -270,7 +270,7 @@ def _mlap_cost(shape: dict, config: dict, dtype: str) -> Cost:
     return Cost(flops, float(hbm), float(vmem), B * H * nq * nk)
 
 
-def _mlad_defaults(shape: dict) -> dict:
+def _mlad_defaults(shape: dict, dtype: str) -> dict:
     return {"block_k": _fa.default_blocks(shape["H"], shape["Lk"])[1]}
 
 
@@ -306,7 +306,7 @@ def _mlad_cost(shape: dict, config: dict, dtype: str) -> Cost:
 # ---------------------------------------------------------------------------
 
 
-def _ss_defaults(shape: dict) -> dict:
+def _ss_defaults(shape: dict, dtype: str) -> dict:
     return {"block_d": _ss.DEFAULT_BLOCK_D}
 
 
@@ -365,7 +365,7 @@ def _ss_cost(shape: dict, config: dict, dtype: str) -> Cost:
 # ---------------------------------------------------------------------------
 
 
-def _rg_defaults(shape: dict) -> dict:
+def _rg_defaults(shape: dict, dtype: str) -> dict:
     return {"block_d": _rg.DEFAULT_BLOCK_D}
 
 
@@ -412,12 +412,9 @@ def _rg_cost(shape: dict, config: dict, dtype: str) -> Cost:
 # ---------------------------------------------------------------------------
 
 
-def _gmm_defaults(shape: dict) -> dict:
-    return {
-        "block_c": _gmm.DEFAULT_BLOCK_C,
-        "block_f": _gmm.DEFAULT_BLOCK_F,
-        "block_d": _gmm.DEFAULT_BLOCK_D,
-    }
+def _gmm_defaults(shape: dict, dtype: str) -> dict:
+    block_c, block_f, block_d = _gmm.default_blocks(shape["C"], shape["D"], shape["F"], _isz(dtype))
+    return {"block_c": block_c, "block_f": block_f, "block_d": block_d}
 
 
 def _gmm_make_args(shape: dict, dtype: str, seed: int) -> tuple:
@@ -445,9 +442,9 @@ def _gmm_ref(shape: dict, args: tuple):
 def _gmm_space(shape: dict) -> list:
     return [
         {"block_c": bc, "block_f": bf, "block_d": bd}
-        for bc in _divisors(shape["C"], candidates=(32, 64, 128, 256))
-        for bf in _divisors(shape["F"], candidates=(64, 128, 256, 512))
-        for bd in _divisors(shape["D"], candidates=(128, 256, 512))
+        for bc in _divisors(shape["C"], candidates=(32, 64, 128, 256, 512, 1024))
+        for bf in _divisors(shape["F"], candidates=(64, 128, 256, 512, 1024))
+        for bd in _divisors(shape["D"], candidates=(128, 256, 512, 1024))
     ]
 
 
@@ -463,9 +460,7 @@ def _gmm_cost(shape: dict, config: dict, dtype: str) -> Cost:
     # x tiles re-fetched per f-block, w tiles per c-block, y written per
     # d-block (interpret copies the out tile back every cell)
     hbm = isz * (nf * E * C * D + nc * E * D * F + nd * E * C * F)
-    # double-buffered x/w/y tiles + the fp32 accumulator
-    vmem = 2 * isz * (bc * bd + bd * bf + bc * bf) + 4 * bc * bf
-    return Cost(flops, float(hbm), float(vmem), cells)
+    return Cost(flops, float(hbm), float(_gmm.vmem_bytes(bc, bd, bf, isz)), cells)
 
 
 # ---------------------------------------------------------------------------

@@ -67,9 +67,9 @@ def test_vmem_budget_filters_and_degenerate_budget_falls_back_to_defaults():
     # rather than an empty sweep, and tune must still return a usable config
     tiny = _model_tuner(vmem_budget=1)
     survivors, exhaustive = tiny.prune(name, shape)
-    assert survivors == [kdef.defaults(shape)]
+    assert survivors == [kdef.defaults(shape, "float32")]
     assert exhaustive == len(kdef.space(shape))
-    assert tiny.tune(name, shape).config == kdef.defaults(shape)
+    assert tiny.tune(name, shape).config == kdef.defaults(shape, "float32")
     # a budget that only admits the smallest block: the winner shrinks
     smallest = kdef.cost(shape, {"block_d": 32}, "float32").vmem_bytes
     capped = _model_tuner(vmem_budget=int(smallest))

@@ -45,7 +45,7 @@ def test_kernel_runtime_executes_and_advances_progress():
     assert task.progress_frac == 1.0
     assert task.kernel_stats["reps"] == 2
     assert task.kernel_stats["config"] == kreg.config_sig(
-        kreg.get_kernel("moe_gmm").defaults(kreg.get_kernel("moe_gmm").tiny_shape)
+        kreg.get_kernel("moe_gmm").defaults(kreg.get_kernel("moe_gmm").tiny_shape, "float32")
     )
 
 
@@ -227,7 +227,7 @@ def test_broker_kernel_tasks_consult_tuned_cache_under_gate(tmp_path, monkeypatc
     tuner = h.enable_kernel_autotune(timer="model")
     kdef = kreg.get_kernel("rglru_scan")
     tuned = tuner.tune("rglru_scan", dict(kdef.tiny_shape), "float32")
-    default_sig = kreg.config_sig(kdef.defaults(kdef.tiny_shape))
+    default_sig = kreg.config_sig(kdef.defaults(kdef.tiny_shape, "float32"))
     assert kreg.config_sig(tuned.config) != default_sig  # a real contrast
 
     monkeypatch.setenv("HYDRA_AUTOTUNE", "1")

@@ -99,7 +99,7 @@ def test_flash_attention_one_tile_rule(monkeypatch):
         kv = jax.ShapeDtypeStruct((1, 2, L, 128), jnp.bfloat16)
         ops.flash_attention(q, kv, kv)
         shape = {"B": 1, "H": 4, "KV": 2, "L": L, "hd": 128, "causal": True, "window": None}
-        registry = kreg.get_kernel("flash_attention").defaults(shape)
+        registry = kreg.get_kernel("flash_attention").defaults(shape, "bfloat16")
         assert (seen["block_q"], seen["block_k"]) == fa.default_blocks(L, L) == (bq, bk)
         assert registry == {"block_q": bq, "block_k": bk}
 
@@ -223,6 +223,55 @@ def test_moe_gmm_sweep(dtype, E, C, D, F):
     x = jnp.asarray(RNG.normal(size=(E, C, D)), dtype)
     w = jnp.asarray(RNG.normal(size=(E, D, F)) * 0.1, dtype)
     got = ops.moe_gmm(x, w, block_c=32, block_f=64, block_d=64)
+    want = ref.moe_gmm_ref(x, w)
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32), np.asarray(want, np.float32), **_tol(dtype)
+    )
+
+
+def test_moe_gmm_default_blocks(monkeypatch):
+    """One tile rule: at Grok-1's expert every tile divides its dimension,
+    C is one block and the tiles fit the VMEM budget; small shapes clamp to
+    their dimensions; ops.moe_gmm and the registry's defaults agree."""
+    from repro.kernels import moe_gmm as gmm
+    from repro.kernels import registry as kreg
+
+    C, D, F = 1024, 6144, 32768
+    for dtype in ("bfloat16", "float32"):
+        isz = jnp.dtype(dtype).itemsize
+        bc, bf, bd = gmm.default_blocks(C, D, F, isz)
+        assert C % bc == 0 and F % bf == 0 and D % bd == 0
+        assert bc == C
+        assert gmm.vmem_bytes(bc, bd, bf, isz) <= gmm.VMEM_BUDGET
+    assert gmm.default_blocks(C, D, F, 2) == (1024, 1024, 512)
+    assert gmm.default_blocks(64, 128, 128, 4) == (64, 128, 128)
+    assert gmm.default_blocks(32, 64, 96, 2) == (32, 96, 64)
+    assert gmm.default_blocks(384, 768, 1536, 4) == (128, 512, 256)
+
+    monkeypatch.delenv("HYDRA_AUTOTUNE", raising=False)
+    seen = {}
+    monkeypatch.setattr(ops, "_moe_gmm_jit", lambda x, w, **kw: seen.update(kw))
+    for (E, C, D, F), dtype in [((1, 1024, 6144, 32768), jnp.bfloat16), ((2, 64, 128, 256), jnp.float32)]:
+        ops.moe_gmm(jax.ShapeDtypeStruct((E, C, D), dtype), jax.ShapeDtypeStruct((E, D, F), dtype))
+        shape = {"E": E, "C": C, "D": D, "F": F}
+        registry = kreg.get_kernel("moe_gmm").defaults(shape, jnp.dtype(dtype).name)
+        rule = gmm.default_blocks(C, D, F, jnp.dtype(dtype).itemsize)
+        assert (seen["block_c"], seen["block_f"], seen["block_d"]) == rule
+        assert registry == dict(zip(("block_c", "block_f", "block_d"), rule))
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_moe_gmm_rule_tiles_match_reference(dtype):
+    """The rule's tiles with two blocks on F and on D: the float32
+    accumulator carries across D and every F block lands in place."""
+    from repro.kernels import moe_gmm as gmm
+
+    E, C, D, F = 2, 256, 1024, 2048
+    bc, bf, bd = gmm.default_blocks(C, D, F, jnp.dtype(dtype).itemsize)
+    assert F // bf > 1 and D // bd > 1
+    x = jnp.asarray(RNG.normal(size=(E, C, D)), dtype)
+    w = jnp.asarray(RNG.normal(size=(E, D, F)) / D**0.5, dtype)
+    got = ops.moe_gmm(x, w)
     want = ref.moe_gmm_ref(x, w)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), **_tol(dtype)

@@ -52,7 +52,7 @@ def no_persistent_cache():
 def _compile(name: str, shape: dict, dtype: str, device):
     kdef = kreg.get_kernel(name)
     assert not kreg.interpret_default(device)
-    return kreg.compiled(kdef, shape, dtype, kdef.defaults(shape), device)
+    return kreg.compiled(kdef, shape, dtype, kdef.defaults(shape, dtype), device)
 
 
 @pytest.mark.parametrize("name,dtype", PUBLISHED)
