@@ -71,7 +71,7 @@ def kernel_rows(full: bool = False) -> list[tuple]:
         args = kdef.make_args(shape, "float32", 0)
         t_ref = _time_us(lambda: kdef.ref(shape, args))
         err = kreg.max_abs_err(
-            kdef.call(shape, args, kdef.defaults(shape, "float32"), interpret),
+            kdef.call(shape, args, kreg.block_config(kdef, shape, "float32"), interpret),
             kdef.ref(shape, args),
         )
         rows.append(
@@ -91,7 +91,7 @@ def exp14_row(reps: int = 3) -> tuple:
         result = tuner.tune(name, shape, "float32")
         min_cut = min(min_cut, result.sweep_cut)
         args = kdef.make_args(shape, "float32", 0)
-        default = kdef.defaults(shape, "float32")
+        default = kreg.block_config(kdef, shape, "float32")
         default_s = _min_s(lambda: kdef.call(shape, args, default, interpret), reps)
         tuned_s = _min_s(
             lambda: kdef.call(shape, args, result.config, interpret), reps

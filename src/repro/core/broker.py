@@ -43,7 +43,7 @@ from repro.core.partition import partition
 from repro.core.pod import Pod, make_store
 from repro.core.policy import Policy, make_policy
 from repro.core.provider import ProviderHandle, ProviderProxy, ProviderSpec
-from repro.core.staging import LinkModel, StagingService
+from repro.core.staging import SHARED_SITE, LinkModel, StagingService
 from repro.core.task import Task, TaskState
 from repro.runtime.clock import guard_wait
 from repro.runtime.tracing import Metrics, Trace, compute_metrics, now, span, trace_gc
@@ -286,21 +286,20 @@ class Hydra:
         self, *, timer: str = "wall", reps: int = 3, seed: int = 0
     ):
         """Attach a Pallas Autotuner (kernels/autotune.py): sweeps land as
-        pinned replicated datasets in this broker's staging registry and
-        cache misses emit ``kernel.tune`` on this broker's bus.  The tuner
-        is also installed process-global so kernels/ops.py entry points
-        (and kernel-payload tasks) consult it under ``HYDRA_AUTOTUNE=1``.
-        Lazy import: the kernels package pulls jax, which the broker core
-        must not pay for unconditionally."""
-        from repro.kernels.autotune import Autotuner, set_autotuner
+        pinned datasets on the shared store of this broker's staging
+        registry and cache misses emit ``kernel.tune`` on this broker's bus.
+        A tuned config reaches a kernel in the task's payload
+        (``{"kernel": ..., "config": result.config}``); nothing consults
+        the tuner on the task path.  Lazy import: the kernels package pulls
+        jax, which the broker core must not pay for unconditionally."""
+        from repro.kernels.autotune import Autotuner
 
         if self.autotuner is not None:
             raise RuntimeError("a kernel autotuner is already attached")
         self.autotuner = Autotuner(
-            registry=self.staging.registry, events=self.events,
+            registry=self.staging.registry, site=SHARED_SITE, events=self.events,
             timer=timer, reps=reps, seed=seed,
         )
-        set_autotuner(self.autotuner)
         return self.autotuner
 
     def dispatch(self, tasks: list[Task]) -> None:
@@ -1449,12 +1448,6 @@ class Hydra:
         self._dispatch.shutdown(wait=wait)
         self.staging.shutdown()
         self.store.cleanup()
-        if self.autotuner is not None:
-            # release the process-global slot iff it is still ours (a later
-            # broker may have installed its own tuner in the meantime)
-            from repro.kernels.autotune import unset_autotuner
-
-            unset_autotuner(self.autotuner)
         log_base = os.environ.get("HYDRA_EVENTS_LOG", "")
         if log_base:
             self.events.dump_jsonl(next_log_path(log_base))

@@ -147,10 +147,10 @@ class KernelRuntime:
          "dtype": "float32", "reps": 3, "seed": 0,
          "config": {"block_d": 512}}        # optional explicit blocks
 
-    Block-config resolution mirrors kernels/ops.py: explicit payload config
-    > autotuned cache (``HYDRA_AUTOTUNE=1`` only) > the kernel's committed
-    defaults.  Operands are built on ``device`` from the seed and the kernel
-    runs there as one compiled program (kernels/registry.py ``compiled``).
+    ``registry.block_config`` turns the payload's ``config`` into the
+    blocks the kernel runs (a tuned config reaches a kernel this way).
+    Operands are built on ``device`` from the seed and the kernel runs
+    there as one compiled program (kernels/registry.py ``compiled``).
     Execution is rep-granular and resumable: ``progress_frac`` advances
     after every completed repetition, so a preempt-killed task that the
     checkpointer resumes (ckpt/checkpoint.py) skips the reps it already
@@ -164,7 +164,6 @@ class KernelRuntime:
         import jax
 
         from repro.kernels import registry as kreg
-        from repro.kernels.autotune import tuned_config
 
         spec = dict(task.payload or {})
         kdef = kreg.get_kernel(spec["kernel"])
@@ -172,7 +171,7 @@ class KernelRuntime:
         dtype = spec.get("dtype", "float32")
         reps = max(1, int(spec.get("reps", 1)))
         seed = int(spec.get("seed", 0))
-        config = spec.get("config") or tuned_config(kdef.name, shape, dtype) or kdef.defaults(shape, dtype)
+        config = kreg.block_config(kdef, shape, dtype, spec.get("config"))
         program = kreg.compiled(kdef, shape, dtype, config, device)
         with span("kernel.operands", uid=task.uid):
             args = kreg.operands(kdef, shape, dtype, seed, device)

@@ -3,9 +3,10 @@
 Sweeps the block/grid configs of every registered kernel per (device,
 problem shape), prunes the sweep with the roofline cost model before any
 candidate runs, times the survivors (warm-up + min-of-N), and caches each
-winner as a *replicated dataset* in the broker's staging registry — so
-tuned configs flow through data-gravity placement and survive site death
-exactly like any other artifact.
+winner as a pinned dataset in the staging registry its caller gives it
+(``Hydra.enable_kernel_autotune`` passes the broker's registry and its
+shared site) — so tuned configs flow through data-gravity placement and
+survive site death exactly like any other artifact.
 
 Pruning (the "provably dominated" rule)
 ---------------------------------------
@@ -41,15 +42,14 @@ above, priced at the published peaks of a TPU v5e — fully deterministic,
 used by the determinism tests and the dry-run report's predicted-config
 rows.
 
-``ops.py`` consults the process-global tuner (:func:`tuned_config`) only
-when ``HYDRA_AUTOTUNE=1``; with the gate off every entry point falls back
-to the kernels' committed defaults, bit-identical to the pre-autotune
-behavior.
+Nothing on the call path consults the tuner.  A tuned config reaches a
+kernel the way any explicit config does: the caller puts
+``TuneResult.config`` in a kernel task's payload ``config`` (or passes it
+to ``ops.call``), and ``registry.block_config`` lets it win.
 """
 from __future__ import annotations
 
 import json
-import os
 import threading
 import time
 from dataclasses import dataclass, field
@@ -72,11 +72,6 @@ MODEL_CELL_OVERHEAD_S = 1e-6
 MODEL_CHIP = peaks(V5E)
 
 PAYLOAD_VERSION = 1
-
-
-def autotune_enabled() -> bool:
-    """The ``HYDRA_AUTOTUNE=1`` gate consulted by kernels/ops.py."""
-    return os.environ.get("HYDRA_AUTOTUNE", "") not in ("", "0")
 
 
 def device_kind() -> str:
@@ -107,12 +102,13 @@ class TuneResult:
 
 class Autotuner:
     """Sweep, prune, time, cache.  One per broker (``Hydra.
-    enable_kernel_autotune``) or process-global for bare ops calls."""
+    enable_kernel_autotune``) or standalone (benchmarks, dry run)."""
 
     def __init__(
         self,
         *,
         registry=None,  # staging DatasetRegistry (winners become datasets)
+        site: Optional[str] = None,  # the registry site winners are pinned on
         events=None,  # EventBus (kernel.tune on cache misses)
         seed: int = 0,
         reps: int = 3,
@@ -121,7 +117,9 @@ class Autotuner:
         vmem_budget: int = VMEM_BUDGET_BYTES,
     ):
         assert timer in ("wall", "model"), timer
+        assert registry is None or site is not None, "a registry needs the site to pin winners on"
         self.registry = registry
+        self.site = site
         self.events = events
         self.seed = seed
         self.reps = reps
@@ -135,14 +133,6 @@ class Autotuner:
         # broker._events_recompute when this tuner is broker-attached)
         self.tunes = 0
         self.swept_configs = 0
-
-    # -- wiring --------------------------------------------------------
-    def attach(self, registry=None, events=None) -> "Autotuner":
-        if registry is not None:
-            self.registry = registry
-        if events is not None:
-            self.events = events
-        return self
 
     # -- keys ----------------------------------------------------------
     def cache_key(self, kernel: str, shape: dict, dtype: str, device: Optional[str] = None) -> str:
@@ -162,7 +152,7 @@ class Autotuner:
         if not fits:
             # every candidate over budget (degenerate tiny-VMEM override):
             # fall back to the kernel defaults rather than an empty sweep
-            return [kdef.defaults(shape, dtype)], exhaustive
+            return [kreg.block_config(kdef, shape, dtype)], exhaustive
 
         def dominated(ci: kreg.Cost) -> bool:
             for _, cj in fits:
@@ -210,9 +200,9 @@ class Autotuner:
     def tune(self, kernel: str, shape: dict, dtype: str = "float32") -> TuneResult:
         """Sweep (or cache-hit) the winning config for one problem.
 
-        Coarse-grained lock: tuning is rare and cache lookups from task
-        threads are cheap; holding the lock across the sweep also keeps
-        the cache-miss event count exact (one ``kernel.tune`` per key)."""
+        Coarse-grained lock: tuning is rare; holding the lock across the
+        sweep keeps the cache-miss event count exact (one ``kernel.tune``
+        per key)."""
         with self._lock:
             key = self.cache_key(kernel, shape, dtype)
             hit = self._results.get(key)
@@ -288,24 +278,15 @@ class Autotuner:
     def _register_dataset(self, key: str, payload: bytes) -> None:
         if self.registry is None:
             return
-        from repro.core.staging import SHARED_SITE
-
-        # pinned shared-store replica: a tuned config is authoritative
-        # metadata, never LRU-evicted, and survives any one site's death
+        # pinned replica on the caller's site (the broker's shared store): a
+        # tuned config is authoritative metadata, never LRU-evicted, and
+        # survives any one site's death
         self.registry.add(
             key, size_mb=max(len(payload) / 1e6, 1e-6),
-            sites=(SHARED_SITE,), pinned=True,
+            sites=(self.site,), pinned=True,
         )
 
-    # -- consultation (the ops.py fast path) ---------------------------
-    def lookup(self, kernel: str, shape: dict, dtype: str = "float32") -> Optional[dict]:
-        """Cached winner for this problem, or None (caller uses defaults).
-        Never triggers a sweep: the dispatch fast path must stay cheap and
-        deterministic."""
-        with self._lock:
-            hit = self._results.get(self.cache_key(kernel, shape, dtype))
-            return dict(hit.config) if hit is not None else None
-
+    # -- results -------------------------------------------------------
     def payload(self, key: str) -> Optional[bytes]:
         with self._lock:
             return self._payloads.get(key)
@@ -317,48 +298,6 @@ class Autotuner:
     def stats(self) -> dict:
         with self._lock:
             return {"tunes": self.tunes, "swept_configs": self.swept_configs}
-
-
-# ---------------------------------------------------------------------------
-# process-global tuner (bare ops.py calls outside any broker)
-# ---------------------------------------------------------------------------
-
-_GLOBAL: Optional[Autotuner] = None
-_GLOBAL_LOCK = threading.Lock()
-
-
-def get_autotuner() -> Autotuner:
-    global _GLOBAL
-    with _GLOBAL_LOCK:
-        if _GLOBAL is None:
-            _GLOBAL = Autotuner()
-        return _GLOBAL
-
-
-def set_autotuner(tuner: Optional[Autotuner]) -> None:
-    """Install (or clear, with None) the process-global tuner consulted by
-    kernels/ops.py under HYDRA_AUTOTUNE=1."""
-    global _GLOBAL
-    with _GLOBAL_LOCK:
-        _GLOBAL = tuner
-
-
-def unset_autotuner(tuner: Autotuner) -> None:
-    """Clear the global slot only if ``tuner`` still owns it (broker
-    shutdown must not clobber a successor broker's tuner)."""
-    global _GLOBAL
-    with _GLOBAL_LOCK:
-        if _GLOBAL is tuner:
-            _GLOBAL = None
-
-
-def tuned_config(kernel: str, shape: dict, dtype: str = "float32") -> Optional[dict]:
-    """Env-gated cache consultation for the ops.py entry points: None when
-    the gate is off or the problem was never tuned (deterministic fallback
-    to the committed defaults)."""
-    if not autotune_enabled():
-        return None
-    return get_autotuner().lookup(kernel, shape, dtype)
 
 
 def predict_best(kernel: str, shape: dict, dtype: str = "float32") -> dict:
@@ -389,10 +328,5 @@ __all__ = [
     "VMEM_BUDGET_BYTES",
     "TuneResult",
     "Autotuner",
-    "autotune_enabled",
-    "get_autotuner",
-    "set_autotuner",
-    "unset_autotuner",
-    "tuned_config",
     "predict_best",
 ]

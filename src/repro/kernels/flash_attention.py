@@ -18,7 +18,7 @@ Operand dtype
 
 Tiles
   ``default_blocks`` is the one tile rule for every caller without an
-  explicit config (ops.flash_attention, registry defaults): block_q is the
+  explicit config (registry.block_config): block_q is the
   largest of 512, 256 and 128 that divides the query length, block_k the
   largest of 1024, 512, 256 and 128 that divides the key length, each else
   the length itself.  At Grok-1's 8k context that is 512 x 1024: 16 x 8

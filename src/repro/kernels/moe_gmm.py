@@ -11,7 +11,7 @@ once, after the last D block.
 
 Tiles
   ``default_blocks`` is the one tile rule for every caller without an
-  explicit config (ops.moe_gmm, registry defaults).  Per axis it takes the
+  explicit config (registry.block_config).  Per axis it takes the
   largest candidate tile that divides the dimension, else the dimension
   itself, and steps block_d, then block_f, then block_c down until the
   tiles fit the VMEM budget.  block_c comes first because it sets how often

@@ -19,9 +19,13 @@ cell, q and output tiles once per q row; FLOPs count live cells only
 FLOPs for fewer cell launches and less re-fetched K/V — the three-way
 frontier the autotuner prunes on (kernels/autotune.py).
 
+``block_config`` is the one resolver from a call to its blocks: explicit
+blocks win, the kernel's tile rule fills the rest.  ``kdef.call`` is the
+one place a Pallas kernel is called.
+
 Consumers: the autotuner, the ``kind="kernel"`` task runtime
-(core/managers/compute.py), benchmarks/kernels_bench.py, and the parity
-tests.
+(core/managers/compute.py), kernels/ops.py (the jitted entry for traced
+model code), benchmarks/kernels_bench.py, and the parity tests.
 """
 from __future__ import annotations
 
@@ -561,6 +565,14 @@ def get_kernel(name: str) -> KernelDef:
     return kdef
 
 
+def block_config(kdef: KernelDef, shape: dict, dtype, explicit=None) -> dict:
+    """The one place a call becomes a block config: the blocks ``explicit``
+    gives win, every other key comes from the kernel's tile rule
+    (``kdef.defaults``).  Resolved outside any trace: blocks are static."""
+    given = {k: v for k, v in (explicit or {}).items() if v is not None}
+    return {**kdef.defaults(shape, jnp.dtype(dtype).name), **given}
+
+
 def config_sig(config: dict) -> str:
     """Canonical ``k=v`` string of a block config (event attrs, payloads)."""
     return ",".join(f"{k}={config[k]}" for k in sorted(config))
@@ -693,6 +705,7 @@ __all__ = [
     "KernelDef",
     "KERNELS",
     "get_kernel",
+    "block_config",
     "shape_sig",
     "config_sig",
     "interpret_default",

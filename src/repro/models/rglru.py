@@ -140,7 +140,8 @@ def rglru_seq(p: dict, u: jax.Array, h0=None, use_pallas: bool = False):
     if use_pallas:
         from repro.kernels import ops as kops
 
-        y, h_last = kops.rglru_scan(log_a, gx, h0)
+        B, L, dr = log_a.shape
+        y, h_last = kops.call("rglru_scan", {"B": B, "L": L, "dr": dr}, log_a, gx, h0)
         return y.astype(u.dtype), h_last
     a = jnp.exp(log_a)
     if h0 is not None:

@@ -93,9 +93,10 @@ def selective_scan_chunked(cfg: ArchConfig, p, xb, dt, bm, cm, h0=None, use_pall
     if use_pallas:
         from repro.kernels import ops as kops
 
+        shape = {"B": B, "chunk": ck, "di": di, "N": N}
+
         def chunk_body(h, chunk):
-            xc, dtc, bc, cc = chunk
-            y, h_new = kops.selective_scan_chunk(xc, dtc, bc, cc, a, h)
+            y, h_new = kops.call("selective_scan", shape, *chunk, a, h)
             return h_new, y
     elif cfg.ssm_scan == "seq":
 

@@ -1,5 +1,5 @@
 """Roofline autotuner (kernels/autotune.py): pruning, caching, determinism,
-and the HYDRA_AUTOTUNE consultation gate in kernels/ops.py.
+and how a tuned config reaches a kernel (registry.block_config).
 
 The determinism contract is the load-bearing one: under ``timer="model"``
 the whole tune is a pure function of (kernel, shape, dtype, seed), the
@@ -12,15 +12,8 @@ import pytest
 
 from repro.core.events import EventBus
 from repro.core.staging import SHARED_SITE, DatasetRegistry
-from repro.kernels import ops
 from repro.kernels import registry as kreg
-from repro.kernels.autotune import (
-    Autotuner,
-    autotune_enabled,
-    set_autotuner,
-    tuned_config,
-    unset_autotuner,
-)
+from repro.kernels.autotune import Autotuner
 
 # the exp14 demo problem: small batch x full-width feature dim, where the
 # pruner collapses the frontier to the single largest admissible block
@@ -130,7 +123,7 @@ def test_cache_keys_by_device_kind_and_model_prices_named_chip():
 def test_winner_registers_as_pinned_shared_dataset():
     name, shape = DEMO
     registry = DatasetRegistry()
-    tuner = _model_tuner(registry=registry)
+    tuner = _model_tuner(registry=registry, site=SHARED_SITE)
     result = tuner.tune(name, shape)
     assert result.key.startswith(f"tune:{name}:")
     assert result.key.endswith(kreg.shape_sig(shape, "float32"))
@@ -140,68 +133,30 @@ def test_winner_registers_as_pinned_shared_dataset():
 
 
 # ---------------------------------------------------------------------------
-# the HYDRA_AUTOTUNE gate (ops.py consultation path)
+# how a tuned config reaches a kernel: as an explicit config
 # ---------------------------------------------------------------------------
 
 
-@pytest.fixture
-def global_tuner():
-    tuner = _model_tuner()
-    set_autotuner(tuner)
-    yield tuner
-    unset_autotuner(tuner)
-
-
-def test_tuned_config_is_env_gated(monkeypatch, global_tuner):
+def test_ops_resolution_order_explicit_beats_tuned_beats_default():
+    """registry.block_config, the one resolver: a full explicit config (a
+    tuned winner is passed as one) wins outright; a partial one is filled
+    from the kernel's tile rule; none gives the rule's config."""
     name, shape = DEMO
-    global_tuner.tune(name, shape)
-    monkeypatch.delenv("HYDRA_AUTOTUNE", raising=False)
-    assert not autotune_enabled()
-    assert tuned_config(name, shape) is None  # gate off: defaults path
-    monkeypatch.setenv("HYDRA_AUTOTUNE", "0")
-    assert tuned_config(name, shape) is None
-    monkeypatch.setenv("HYDRA_AUTOTUNE", "1")
-    assert tuned_config(name, shape) == {"block_d": 1024}
-    # never-tuned problems fall back to None even with the gate on
-    assert tuned_config(name, {"B": 2, "L": 64, "dr": 256}) is None
+    kdef = kreg.get_kernel(name)
+    tuned = _model_tuner().tune(name, shape).config
+    assert tuned != kdef.defaults(shape, "float32")  # a real contrast
+    assert kreg.block_config(kdef, shape, "float32", tuned) == tuned
+    assert kreg.block_config(kdef, shape, "float32", {"block_d": 64}) == {"block_d": 64}
+    assert kreg.block_config(kdef, shape, "float32") == kdef.defaults(shape, "float32")
+    assert kreg.block_config(kdef, shape, "float32", {"block_d": None}) == {"block_d": 512}
 
-
-def test_ops_resolution_order_explicit_beats_tuned_beats_default(
-    monkeypatch, global_tuner
-):
-    name, shape = DEMO
-    global_tuner.tune(name, shape)
-    monkeypatch.setenv("HYDRA_AUTOTUNE", "1")
-    import jax.numpy as jnp
-
-    defaults = {"block_d": 512}
-    assert ops._resolve(name, shape, jnp.float32, defaults, {"block_d": 64}) == {
-        "block_d": 64
-    }
-    assert ops._resolve(name, shape, jnp.float32, defaults, {"block_d": None}) == {
-        "block_d": 1024
-    }
-    monkeypatch.delenv("HYDRA_AUTOTUNE")
-    assert ops._resolve(name, shape, jnp.float32, defaults, {"block_d": None}) == {
-        "block_d": 512
-    }
-
-
-def test_unset_autotuner_only_clears_its_own_installation():
-    a, b = _model_tuner(), _model_tuner()
-    set_autotuner(a)
-    unset_autotuner(b)  # a stale shutdown must not clobber the live tuner
-    name, shape = DEMO
-    a.tune(name, shape)
-    try:
-        import os
-
-        os.environ["HYDRA_AUTOTUNE"] = "1"
-        assert tuned_config(name, shape) is not None
-    finally:
-        os.environ.pop("HYDRA_AUTOTUNE", None)
-        unset_autotuner(a)
-    assert tuned_config(name, shape) is None
+    gmm = kreg.get_kernel("moe_gmm")
+    gshape = {"E": 1, "C": 1024, "D": 6144, "F": 32768}
+    rule = gmm.defaults(gshape, "bfloat16")
+    assert rule == {"block_c": 1024, "block_f": 1024, "block_d": 512}
+    assert kreg.block_config(gmm, gshape, "bfloat16", {"block_f": 256}) == {**rule, "block_f": 256}
+    full = {"block_c": 128, "block_f": 256, "block_d": 128}
+    assert kreg.block_config(gmm, gshape, "bfloat16", full) == full
 
 
 @pytest.mark.parametrize("causal,window,live", [(False, None, 16), (True, None, 10), (True, 64, 7)])

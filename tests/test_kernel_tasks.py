@@ -4,8 +4,8 @@ Covers the whole payload path — the KernelRuntime's rep-granular resume
 contract (managers/compute.py), the checkpointer's kernel branch (progress
 IS the checkpoint: lost_s == 0), a live broker executing one task per
 registered kernel with ``kernel.exec`` accounting reconciling under
-HYDRA_EVENTS_CHECK=1, tuned-config consultation under HYDRA_AUTOTUNE=1,
-and the acceptance scenario: a searise run whose serve lane dispatches
+HYDRA_EVENTS_CHECK=1, a tuned config carried in the payload, and the
+acceptance scenario: a searise run whose serve lane dispatches
 kernel payloads completes with ZERO failed tasks under the PR-6 correlated
 fault schedule."""
 from __future__ import annotations
@@ -22,7 +22,7 @@ from repro.core import Hydra, ProviderSpec, Task, TaskState
 from repro.core.events import EventBus
 from repro.core.provider import ValidationError
 from repro.core.managers.compute import KERNEL_RUNTIME
-from repro.core.staging import DatasetRegistry
+from repro.core.staging import SHARED_SITE, DatasetRegistry
 from repro.ckpt.checkpoint import TaskCheckpointer
 from repro.kernels import registry as kreg
 from repro.scenarios import presets
@@ -222,7 +222,10 @@ def test_four_providers_run_on_four_devices():
     assert "FOUR_DEVICES_OK" in out.stdout, out.stderr[-3000:]
 
 
-def test_broker_kernel_tasks_consult_tuned_cache_under_gate(tmp_path, monkeypatch):
+def test_broker_kernel_tasks_consult_tuned_cache_under_gate(tmp_path):
+    """A tuned config reaches a kernel in the payload: the task that
+    carries it reports it, the task without one reports the tile rule's
+    config, and the one sweep emitted one kernel.tune."""
     h = _kernel_broker(tmp_path)
     tuner = h.enable_kernel_autotune(timer="model")
     kdef = kreg.get_kernel("rglru_scan")
@@ -230,25 +233,17 @@ def test_broker_kernel_tasks_consult_tuned_cache_under_gate(tmp_path, monkeypatc
     default_sig = kreg.config_sig(kdef.defaults(kdef.tiny_shape, "float32"))
     assert kreg.config_sig(tuned.config) != default_sig  # a real contrast
 
-    monkeypatch.setenv("HYDRA_AUTOTUNE", "1")
-    gated = Task(kind="kernel", payload={"kernel": "rglru_scan"})
-    h.dispatch([gated])
-    assert wait_until(gated.done, timeout=60.0)
-    assert gated.result()["config"] == kreg.config_sig(tuned.config)
-
-    monkeypatch.delenv("HYDRA_AUTOTUNE")
-    ungated = Task(kind="kernel", payload={"kernel": "rglru_scan"})
-    h.dispatch([ungated])
-    assert wait_until(ungated.done, timeout=60.0)
-    assert ungated.result()["config"] == default_sig
+    carried = Task(kind="kernel", payload={"kernel": "rglru_scan", "config": tuned.config})
+    plain = Task(kind="kernel", payload={"kernel": "rglru_scan"})
+    h.dispatch([carried, plain])
+    assert wait_until(lambda: carried.done() and plain.done(), timeout=60.0)
+    assert carried.result()["config"] == kreg.config_sig(tuned.config)
+    assert plain.result()["config"] == default_sig
 
     assert len([e for e in h.events.events() if e.name == "kernel.tune"]) == 1
     assert h.events.view.get("hydra.kernel.tunes") == 1
+    assert SHARED_SITE in h.staging.registry.locate(tuned.key)
     h.shutdown(wait=True)
-    # shutdown released the process-global tuner installation
-    from repro.kernels import autotune
-
-    assert autotune._GLOBAL is not tuner
 
 
 def test_enable_kernel_autotune_refuses_double_attach(tmp_path):

@@ -1,17 +1,61 @@
 """Pallas kernel sweeps: shapes x dtypes vs the pure-jnp oracles
-(interpret mode on CPU; the same calls lower to Mosaic on TPU)."""
+(interpret mode on CPU; the same calls lower to Mosaic on TPU), through the
+one jitted entry (kernels/ops.py ``call``), and the one block-config
+resolver (registry.block_config) behind every path."""
+import ast
+import dataclasses
+import pathlib
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels import registry as kreg
 
 RNG = np.random.default_rng(42)
 
 
 def _tol(dtype):
     return dict(rtol=2e-2, atol=2e-2) if dtype == jnp.bfloat16 else dict(rtol=2e-5, atol=2e-5)
+
+
+def _attention(q, k, v, causal=True, window=None, **blocks):
+    B, H, L, hd = q.shape
+    shape = {"B": B, "H": H, "KV": k.shape[1], "L": L, "hd": hd, "causal": causal, "window": window}
+    return ops.call("flash_attention", shape, q, k, v, config=blocks)
+
+
+def _scan(x, dt, b, c, a, h0, block_d):
+    B, ck, di = x.shape
+    shape = {"B": B, "chunk": ck, "di": di, "N": b.shape[-1]}
+    return ops.call("selective_scan", shape, x, dt, b, c, a, h0, config={"block_d": block_d})
+
+
+def _rglru(log_a, gx, h0, block_d):
+    B, L, dr = log_a.shape
+    return ops.call("rglru_scan", {"B": B, "L": L, "dr": dr}, log_a, gx, h0, config={"block_d": block_d})
+
+
+def _gmm(x, w, **blocks):
+    E, C, D = x.shape
+    return ops.call("moe_gmm", {"E": E, "C": C, "D": D, "F": w.shape[-1]}, x, w, config=blocks)
+
+
+def _config_ops_hands_to_call(monkeypatch, name, shape, *avals):
+    """The block config ``ops.call`` hands ``kdef.call``, traced on
+    abstract operands: nothing compiles or runs."""
+    kdef, seen = kreg.get_kernel(name), []
+
+    def spy(shape, args, config, interpret):
+        seen.append(config)
+        return args[0]
+
+    monkeypatch.setitem(kreg.KERNELS, name, dataclasses.replace(kdef, call=spy))
+    jax.eval_shape(lambda *a: ops.call(name, shape, *a), *avals)
+    monkeypatch.setitem(kreg.KERNELS, name, kdef)
+    return seen[-1]
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -28,7 +72,7 @@ def test_flash_attention_sweep(dtype, B, H, KV, L, hd, block):
     q = jnp.asarray(RNG.normal(size=(B, H, L, hd)), dtype)
     k = jnp.asarray(RNG.normal(size=(B, KV, L, hd)), dtype)
     v = jnp.asarray(RNG.normal(size=(B, KV, L, hd)), dtype)
-    got = ops.flash_attention(q, k, v, causal=True, block_q=block, block_k=block)
+    got = _attention(q, k, v, causal=True, block_q=block, block_k=block)
     want = ref.attention_ref(q, k, v, causal=True)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), **_tol(dtype)
@@ -41,7 +85,7 @@ def test_flash_attention_windowed(window):
     q = jnp.asarray(RNG.normal(size=(B, H, L, hd)), jnp.float32)
     k = jnp.asarray(RNG.normal(size=(B, KV, L, hd)), jnp.float32)
     v = jnp.asarray(RNG.normal(size=(B, KV, L, hd)), jnp.float32)
-    got = ops.flash_attention(q, k, v, causal=True, window=window, block_q=64, block_k=64)
+    got = _attention(q, k, v, causal=True, window=window, block_q=64, block_k=64)
     want = ref.attention_ref(q, k, v, causal=True, window=window)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
 
@@ -51,13 +95,12 @@ def test_flash_attention_non_causal():
     q = jnp.asarray(RNG.normal(size=(B, H, L, hd)), jnp.float32)
     k = jnp.asarray(RNG.normal(size=(B, KV, L, hd)), jnp.float32)
     v = jnp.asarray(RNG.normal(size=(B, KV, L, hd)), jnp.float32)
-    got = ops.flash_attention(q, k, v, causal=False, block_q=64, block_k=64)
+    got = _attention(q, k, v, causal=False, block_q=64, block_k=64)
     want = ref.attention_ref(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5, atol=2e-5)
 
 
-# the tiles ops.flash_attention takes from the one rule when no block is
-# given: 384 -> 128 x 128 (no larger tile divides it), 2048 -> 512 x 1024
+# the tiles ops.call takes from the one rule when no block is given: 384 -> 128 x 128 (no larger tile divides it), 2048 -> 512 x 1024
 _MASKS = {
     "causal": {"causal": True, "window": None},
     "windowed": {"causal": True, "window": 100},
@@ -74,7 +117,7 @@ def test_flash_attention_default_tiles(dtype, mask, L):
     q = jnp.asarray(RNG.normal(size=(B, H, L, hd)), dtype)
     k = jnp.asarray(RNG.normal(size=(B, KV, L, hd)), dtype)
     v = jnp.asarray(RNG.normal(size=(B, KV, L, hd)), dtype)
-    got = ops.flash_attention(q, k, v, **_MASKS[mask])
+    got = _attention(q, k, v, **_MASKS[mask])
     want = ref.attention_ref(q, k, v, **_MASKS[mask])
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), **_tol(dtype)
@@ -82,14 +125,11 @@ def test_flash_attention_default_tiles(dtype, mask, L):
 
 
 def test_flash_attention_one_tile_rule(monkeypatch):
-    """ops.flash_attention and the registry's defaults resolve the same
-    tiles, both from flash_attention.default_blocks."""
+    """The one resolver gives ops.call and every other path the tiles of
+    flash_attention.default_blocks."""
     from repro.kernels import flash_attention as fa
-    from repro.kernels import registry as kreg
 
-    monkeypatch.delenv("HYDRA_AUTOTUNE", raising=False)
-    seen = {}
-    monkeypatch.setattr(ops, "_flash_attention_jit", lambda q, k, v, **kw: seen.update(kw))
+    kdef = kreg.get_kernel("flash_attention")
     want = {
         64: (64, 64), 128: (128, 128), 192: (192, 192), 384: (128, 128),
         768: (256, 256), 1024: (512, 1024), 1536: (512, 512), 8192: (512, 1024),
@@ -97,11 +137,11 @@ def test_flash_attention_one_tile_rule(monkeypatch):
     for L, (bq, bk) in want.items():
         q = jax.ShapeDtypeStruct((1, 4, L, 128), jnp.bfloat16)
         kv = jax.ShapeDtypeStruct((1, 2, L, 128), jnp.bfloat16)
-        ops.flash_attention(q, kv, kv)
         shape = {"B": 1, "H": 4, "KV": 2, "L": L, "hd": 128, "causal": True, "window": None}
-        registry = kreg.get_kernel("flash_attention").defaults(shape, "bfloat16")
-        assert (seen["block_q"], seen["block_k"]) == fa.default_blocks(L, L) == (bq, bk)
-        assert registry == {"block_q": bq, "block_k": bk}
+        resolved = kreg.block_config(kdef, shape, "bfloat16")
+        assert fa.default_blocks(L, L) == (bq, bk)
+        assert resolved == {"block_q": bq, "block_k": bk}
+        assert _config_ops_hands_to_call(monkeypatch, "flash_attention", shape, q, kv, kv) == resolved
 
 
 @pytest.mark.parametrize("causal,window", [(True, None), (True, 100), (False, None), (False, 100)])
@@ -110,7 +150,6 @@ def test_flash_attention_masked_cells_copy_nothing(causal, window):
     a live cell its own block, and a masked cell the block before it: the
     pipeline copies no more K/V tiles than there are live cells."""
     from repro.kernels import flash_attention as fa
-    from repro.kernels import registry as kreg
 
     L, bq, bk = 1024, 128, 256
     band = dict(block_q=bq, block_k=bk, n_kv_blocks=L // bk, causal=causal, window=window)
@@ -160,7 +199,7 @@ def test_selective_scan_sweep(B, ck, di, N, block_d):
     cm = jnp.asarray(RNG.normal(size=(B, ck, N)), jnp.float32)
     a = -jnp.asarray(RNG.uniform(0.5, 2.0, (di, N)), jnp.float32)
     h0 = jnp.asarray(RNG.normal(size=(B, di, N)), jnp.float32)
-    y1, h1 = ops.selective_scan_chunk(x, dt, bm, cm, a, h0, block_d=block_d)
+    y1, h1 = _scan(x, dt, bm, cm, a, h0, block_d=block_d)
     y2, h2 = ref.selective_scan_chunk_ref(x, dt, bm, cm, a, h0)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), rtol=1e-5, atol=1e-5)
@@ -175,7 +214,7 @@ def test_selective_scan_bf16_input():
     cm = jnp.asarray(RNG.normal(size=(B, ck, N)), jnp.float32)
     a = -jnp.asarray(RNG.uniform(0.5, 2.0, (di, N)), jnp.float32)
     h0 = jnp.asarray(RNG.normal(size=(B, di, N)), jnp.float32)
-    y1, h1 = ops.selective_scan_chunk(x, dt, bm, cm, a, h0, block_d=128)
+    y1, h1 = _scan(x, dt, bm, cm, a, h0, block_d=128)
     y2, h2 = ref.selective_scan_chunk_ref(x, dt, bm, cm, a, h0)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), rtol=1e-5, atol=1e-5)
@@ -190,9 +229,9 @@ def test_selective_scan_chains_chunks():
     cm = jnp.asarray(RNG.normal(size=(B, 2 * ck, N)), jnp.float32)
     a = -jnp.asarray(RNG.uniform(0.5, 2.0, (di, N)), jnp.float32)
     h0 = jnp.zeros((B, di, N), jnp.float32)
-    y_full, h_full = ops.selective_scan_chunk(x, dt, bm, cm, a, h0, block_d=32)
-    y1, h1 = ops.selective_scan_chunk(x[:, :ck], dt[:, :ck], bm[:, :ck], cm[:, :ck], a, h0, block_d=32)
-    y2, h2 = ops.selective_scan_chunk(x[:, ck:], dt[:, ck:], bm[:, ck:], cm[:, ck:], a, h1, block_d=32)
+    y_full, h_full = _scan(x, dt, bm, cm, a, h0, block_d=32)
+    y1, h1 = _scan(x[:, :ck], dt[:, :ck], bm[:, :ck], cm[:, :ck], a, h0, block_d=32)
+    y2, h2 = _scan(x[:, ck:], dt[:, ck:], bm[:, ck:], cm[:, ck:], a, h1, block_d=32)
     np.testing.assert_allclose(np.asarray(y_full[:, ck:]), np.asarray(y2), rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(h_full), np.asarray(h2), rtol=1e-5, atol=1e-5)
 
@@ -211,7 +250,7 @@ def test_rglru_sweep(B, L, dr, block_d):
     la = -jnp.asarray(RNG.uniform(0.01, 1.0, (B, L, dr)), jnp.float32)
     gx = jnp.asarray(RNG.normal(size=(B, L, dr)), jnp.float32)
     h0 = jnp.asarray(RNG.normal(size=(B, dr)), jnp.float32)
-    y1, h1 = ops.rglru_scan(la, gx, h0, block_d=block_d)
+    y1, h1 = _rglru(la, gx, h0, block_d=block_d)
     y2, h2 = ref.rglru_ref(la, gx, h0)
     np.testing.assert_allclose(np.asarray(y1), np.asarray(y2), rtol=1e-5, atol=1e-5)
     np.testing.assert_allclose(np.asarray(h1), np.asarray(h2), rtol=1e-5, atol=1e-5)
@@ -222,7 +261,7 @@ def test_rglru_sweep(B, L, dr, block_d):
 def test_moe_gmm_sweep(dtype, E, C, D, F):
     x = jnp.asarray(RNG.normal(size=(E, C, D)), dtype)
     w = jnp.asarray(RNG.normal(size=(E, D, F)) * 0.1, dtype)
-    got = ops.moe_gmm(x, w, block_c=32, block_f=64, block_d=64)
+    got = _gmm(x, w, block_c=32, block_f=64, block_d=64)
     want = ref.moe_gmm_ref(x, w)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), **_tol(dtype)
@@ -232,9 +271,8 @@ def test_moe_gmm_sweep(dtype, E, C, D, F):
 def test_moe_gmm_default_blocks(monkeypatch):
     """One tile rule: at Grok-1's expert every tile divides its dimension,
     C is one block and the tiles fit the VMEM budget; small shapes clamp to
-    their dimensions; ops.moe_gmm and the registry's defaults agree."""
+    their dimensions; the one resolver gives ops.call the rule's tiles."""
     from repro.kernels import moe_gmm as gmm
-    from repro.kernels import registry as kreg
 
     C, D, F = 1024, 6144, 32768
     for dtype in ("bfloat16", "float32"):
@@ -248,16 +286,14 @@ def test_moe_gmm_default_blocks(monkeypatch):
     assert gmm.default_blocks(32, 64, 96, 2) == (32, 96, 64)
     assert gmm.default_blocks(384, 768, 1536, 4) == (128, 512, 256)
 
-    monkeypatch.delenv("HYDRA_AUTOTUNE", raising=False)
-    seen = {}
-    monkeypatch.setattr(ops, "_moe_gmm_jit", lambda x, w, **kw: seen.update(kw))
+    kdef = kreg.get_kernel("moe_gmm")
     for (E, C, D, F), dtype in [((1, 1024, 6144, 32768), jnp.bfloat16), ((2, 64, 128, 256), jnp.float32)]:
-        ops.moe_gmm(jax.ShapeDtypeStruct((E, C, D), dtype), jax.ShapeDtypeStruct((E, D, F), dtype))
+        x, w = jax.ShapeDtypeStruct((E, C, D), dtype), jax.ShapeDtypeStruct((E, D, F), dtype)
         shape = {"E": E, "C": C, "D": D, "F": F}
-        registry = kreg.get_kernel("moe_gmm").defaults(shape, jnp.dtype(dtype).name)
+        resolved = kreg.block_config(kdef, shape, dtype)
         rule = gmm.default_blocks(C, D, F, jnp.dtype(dtype).itemsize)
-        assert (seen["block_c"], seen["block_f"], seen["block_d"]) == rule
-        assert registry == dict(zip(("block_c", "block_f", "block_d"), rule))
+        assert resolved == dict(zip(("block_c", "block_f", "block_d"), rule))
+        assert _config_ops_hands_to_call(monkeypatch, "moe_gmm", shape, x, w) == resolved
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
@@ -271,7 +307,7 @@ def test_moe_gmm_rule_tiles_match_reference(dtype):
     assert F // bf > 1 and D // bd > 1
     x = jnp.asarray(RNG.normal(size=(E, C, D)), dtype)
     w = jnp.asarray(RNG.normal(size=(E, D, F)) / D**0.5, dtype)
-    got = ops.moe_gmm(x, w)
+    got = _gmm(x, w)
     want = ref.moe_gmm_ref(x, w)
     np.testing.assert_allclose(
         np.asarray(got, np.float32), np.asarray(want, np.float32), **_tol(dtype)
@@ -314,3 +350,66 @@ def test_rglru_seq_with_pallas_matches_xla():
     y_pallas, h_pallas = rglru.rglru_seq(p, u, h0, use_pallas=True)
     np.testing.assert_allclose(np.asarray(y_xla), np.asarray(y_pallas), rtol=2e-4, atol=2e-4)
     np.testing.assert_allclose(np.asarray(h_xla), np.asarray(h_pallas), rtol=2e-4, atol=2e-4)
+
+
+class _NoBuild(Exception):
+    pass
+
+
+@pytest.mark.parametrize("name", sorted(kreg.published_shapes()))
+def test_every_path_resolves_the_tile_rule(monkeypatch, name):
+    """At published widths, ops.call, KernelRuntime and the program key of
+    registry.compiled all carry the kernel's tile rule (``kdef.defaults``);
+    nothing compiles or runs there.  A real task runs at the tiny shape."""
+    from repro.core import Task
+    from repro.core.managers.compute import KERNEL_RUNTIME
+
+    kdef = kreg.get_kernel(name)
+    _, shape, dtypes = kreg.published_shapes()[name]
+    device = jax.devices()[0]
+    for dtype in dtypes:
+        want = kdef.defaults(shape, dtype)
+        assert kreg.block_config(kdef, shape, dtype) == want
+        avals = jax.eval_shape(lambda: kdef.make_args(shape, dtype, 0))
+        assert _config_ops_hands_to_call(monkeypatch, name, shape, *avals) == want
+        keys = []
+
+        def no_build(key, build):
+            keys.append(key)
+            raise _NoBuild
+
+        with monkeypatch.context() as m:
+            m.setattr(kreg, "_program", no_build)
+            with pytest.raises(_NoBuild):
+                KERNEL_RUNTIME.run(Task(kind="kernel", payload={"kernel": name, "shape": shape, "dtype": dtype}), device)
+        assert keys == [("call", name, kreg.shape_sig(shape, dtype), kreg.config_sig(want), device)]
+
+    result = KERNEL_RUNTIME.run(Task(kind="kernel", payload={"kernel": name}), device)
+    assert result["config"] == kreg.config_sig(kdef.defaults(kdef.tiny_shape, "float32"))
+
+
+def _imports(path: pathlib.Path) -> list:
+    """Every module a file imports, lazy imports inside functions included."""
+    package = ["repro", "kernels"]
+    names = []
+    for node in ast.walk(ast.parse(path.read_text())):
+        if isinstance(node, ast.Import):
+            names += [a.name for a in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            parts = package[: len(package) - node.level + 1] if node.level else []
+            base = ".".join(parts + ([node.module] if node.module else []))
+            names += [base] + [f"{base}.{a.name}" for a in node.names]
+    return names
+
+
+def test_kernel_layer_imports_nothing_from_the_broker():
+    """No module under src/repro/kernels/ imports repro.core: the kernel
+    layer is called by the broker, never the other way round."""
+    root = pathlib.Path(kreg.__file__).parent
+    found = [
+        (path.name, name)
+        for path in sorted(root.rglob("*.py"))
+        for name in _imports(path)
+        if name == "repro.core" or name.startswith("repro.core.")
+    ]
+    assert not found, found

@@ -40,7 +40,7 @@ def test_parity_at_payload_and_bench_shapes(name, tier):
     tiny (the kind="kernel" payload default) and smoke (BENCH_smoke rows)."""
     kdef = kreg.get_kernel(name)
     shape = dict(kdef.tiny_shape if tier == "tiny" else kdef.smoke_shape)
-    assert _parity_err(name, shape, kdef.defaults(shape, "float32")) <= TOL
+    assert _parity_err(name, shape, kreg.block_config(kdef, shape, "float32")) <= TOL
 
 
 @pytest.mark.parametrize("name", sorted(kreg.KERNELS))

@@ -90,7 +90,7 @@ def test_bfloat16_payload_within_its_tolerance(kernel):
     shape = dict(SMALL, Lq=64) if kernel == "mla_prefill" else dict(SMALL)
     kdef = kreg.get_kernel(kernel)
     args = kdef.make_args(shape, "bfloat16", 1)
-    out = kdef.call(shape, args, kdef.defaults(shape, "bfloat16"), True)
+    out = kdef.call(shape, args, kreg.block_config(kdef, shape, "bfloat16"), True)
     assert out.dtype == jnp.bfloat16
     assert _err(out, kdef.ref(shape, args)) <= TOL_BF16
 
@@ -102,9 +102,9 @@ def test_float8_operands_and_a_missing_yarn_factor_fail_the_tolerances(kernel):
     args = kdef.make_args(shape, "float32", 2)
     want = kdef.ref(shape, args)
     f8 = tuple(a.astype(jnp.float8_e4m3fn).astype(jnp.float32) for a in args)
-    assert _err(kdef.call(shape, f8, kdef.defaults(shape, "float32"), True), want) > TOL_BF16
+    assert _err(kdef.call(shape, f8, kreg.block_config(kdef, shape, "float32"), True), want) > TOL_BF16
     no_yarn = dict(shape, scale=(shape["dn"] + shape["dr"]) ** -0.5)
-    assert _err(kdef.call(no_yarn, args, kdef.defaults(shape, "float32"), True), want) > TOL_BF16
+    assert _err(kdef.call(no_yarn, args, kreg.block_config(kdef, shape, "float32"), True), want) > TOL_BF16
 
 
 def test_yarn_scale():
